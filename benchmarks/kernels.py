@@ -63,7 +63,7 @@ from repro.kernels.fused_select.ops import (fused_select,
                                             fused_select_packed)
 from repro.kernels.intersect_count.ops import intersect_count
 
-_INF = jnp.int32(0x7FFFFFFF)
+_INF = np.int32(0x7FFFFFFF)
 
 
 def _timed(fn, *args, repeats: int):

@@ -1,26 +1,21 @@
-"""Distributed MBE with work stealing on simulated devices.
+"""Distributed MBE with work stealing across every device of the process.
 
     PYTHONPATH=src python examples/mbe_distributed.py
 
-Re-executes itself with 8 simulated XLA host devices (the paper's
-thread-block grid, scaled down) and enumerates a workload-imbalanced
-power-law graph through the unified client (``repro.api.MBEClient``):
+On a CPU host the process gives itself 8 simulated XLA host devices (the
+paper's thread-block grid, scaled down) before JAX starts; on a TPU host
+it uses the chips it has.  It enumerates a workload-imbalanced power-law
+graph through the unified client (``repro.api.MBEClient``):
 ``big_graph_threshold=1`` routes the whole graph to the work-stealing
-big-graph lane across the 8-device serving mesh.  Runs with and without
+big-graph lane across the serving mesh.  Runs with and without
 the round-based work-stealing rebalance (``work_stealing=False`` is the
 paper's noWS ablation) and prints the per-worker busy-step distribution
 — the live version of the paper's Figure 5.
 """
 import os
-import subprocess
-import sys
 
-_CHILD = "REPRO_MBE_EXAMPLE_CHILD"
-
-if _CHILD not in os.environ:
-    env = dict(os.environ, **{_CHILD: "1"})
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    raise SystemExit(subprocess.call([sys.executable, __file__], env=env))
+# host-platform devices only: the flag does not touch a TPU backend
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np          # noqa: E402
 import jax                  # noqa: E402

@@ -49,17 +49,6 @@ from repro.core import engine_dense as ed
 from repro.core.graph import BipartiteGraph
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: ``jax.shard_map`` (new, ``check_vma``)
-    vs ``jax.experimental.shard_map.shard_map`` (0.4.x, ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 @dataclasses.dataclass(frozen=True)
 class DistConfig:
     steps_per_round: int = 4096     # work-stealing barrier period
@@ -208,9 +197,9 @@ def make_round_fn(cfg: ed.EngineConfig, mesh: Mesh,
 
     @jax.jit
     def round_fn(ctx: ed.GraphContext, state: ed.DenseState):
-        return shard_map_compat(
-            _per_device, mesh=mesh,
-            in_specs=(ctx_spec, spec_leaf), out_specs=out_spec)(ctx, state)
+        return jax.shard_map(
+            _per_device, mesh=mesh, in_specs=(ctx_spec, spec_leaf),
+            out_specs=out_spec, check_vma=False)(ctx, state)
 
     return round_fn, n_workers, T
 

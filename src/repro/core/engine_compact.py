@@ -60,7 +60,7 @@ from repro.kernels.fused_check.ops import fused_check_gathered_prefix2
 from repro.kernels.fused_select.ops import fused_select_gathered_prefix
 from repro.kernels.intersect_count.ops import intersect_count
 
-_INF = jnp.int32(0x7FFFFFFF)
+_INF = np.int32(0x7FFFFFFF)
 
 
 class CompactContext(NamedTuple):

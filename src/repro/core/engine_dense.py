@@ -68,7 +68,7 @@ from repro.kernels.resident_pool.ops import (resident_pool_segment,
 from repro.kernels.resident_step.ops import (resident_segment,
                                              resident_supported)
 
-_INF = jnp.int32(0x7FFFFFFF)
+_INF = np.int32(0x7FFFFFFF)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -545,7 +545,7 @@ def pool_lanes(cfg: EngineConfig, batch: int) -> int:
 # per-lane donations are clamped well under int32 range before summing,
 # so a pool of default-budget (1 << 30) finished lanes cannot overflow
 # the surplus accumulator
-_REBALANCE_CLAMP = jnp.int32(1 << 24)
+_REBALANCE_CLAMP = np.int32(1 << 24)
 
 
 def _rebalance_budgets(start: jax.Array, bud: jax.Array, st: DenseState,
@@ -661,19 +661,39 @@ def replace_lane(batch_state: DenseState, batch_ctx: GraphContext, i: int,
     an arbitrary-length request stream — the serving-side analog of cuMBE's
     work stealing for vmap-lane imbalance.
 
-    ``sharding`` (a ``jax.sharding.Sharding``) re-pins every output leaf,
-    for pools whose lane axis lives on a device mesh: the eager scatter
-    does not promise to preserve the input's named sharding, so without
-    the re-pin a surgically-edited pool would silently de-shard and the
-    next round's ``shard_map`` would pay a full reshard.  The ``device_put``
-    allocates fresh buffers (donation-safe: the pre-surgery pool is never
-    aliased into the round executable's donated inputs).
+    ``sharding`` (a ``jax.sharding.Sharding``) is the pool's placement,
+    for pools whose lane axis lives on a device mesh: see
+    ``replace_lanes``.
     """
-    def put(b, lane):
-        out = b.at[i].set(lane)
-        return out if sharding is None else jax.device_put(out, sharding)
-    return (jax.tree.map(put, batch_state, lane_state),
-            jax.tree.map(put, batch_ctx, lane_ctx))
+    def expand(tree):
+        return jax.tree.map(lambda x: jnp.asarray(x)[None], tree)
+    return replace_lanes(batch_state, batch_ctx, [i], expand(lane_state),
+                         expand(lane_ctx), sharding=sharding)
+
+
+def _set_rows_sharded(b: jax.Array, idx: np.ndarray, rows, sharding):
+    """``b.at[idx].set(rows)`` for a pool leaf whose lane axis is sharded
+    over ``sharding``'s devices, done shard by shard: each device scatters
+    the rows it owns into its own shard with a single-device scatter, and
+    untouched shards are reused as they are.  No partitioned scatter runs:
+    on a four-chip TPU v5e mesh, the partitioned ``b.at[idx].set(rows)``
+    installing two lanes at once also wrote into the other lanes' rows of
+    the (B, n) int32 leaves (tasks, order, rank, root_counts), and the
+    served lanes enumerated corrupted graphs."""
+    if b.sharding != sharding:
+        b = jax.device_put(b, sharding)
+    rows = np.asarray(rows)
+    shards = []
+    for sh in b.addressable_shards:
+        lo, hi, _ = sh.index[0].indices(b.shape[0])
+        mine = (idx >= lo) & (idx < hi)
+        data = sh.data
+        if mine.any():
+            data = data.at[idx[mine] - lo].set(
+                jax.device_put(rows[mine], sh.device))
+        shards.append(data)
+    return jax.make_array_from_single_device_arrays(b.shape, sharding,
+                                                    shards)
 
 
 def replace_lanes(batch_state: DenseState, batch_ctx: GraphContext,
@@ -682,12 +702,21 @@ def replace_lanes(batch_state: DenseState, batch_ctx: GraphContext,
     """Vectorized ``replace_lane``: install ``len(idx)`` lanes (leading
     axis of every ``lane_states``/``lane_ctxs`` leaf) with ONE scatter per
     leaf, instead of one full-batch copy per lane — the refill hot path.
-    ``sharding`` re-pins output leaves as in ``replace_lane``."""
-    ii = jnp.asarray(idx, dtype=jnp.int32)
 
-    def put(b, lanes):
-        out = b.at[ii].set(lanes)
-        return out if sharding is None else jax.device_put(out, sharding)
+    ``sharding`` (the pool's ``jax.sharding.Sharding``) switches to
+    shard-local surgery (``_set_rows_sharded``): every output leaf keeps
+    the mesh placement, so the next round's ``shard_map`` pays no
+    reshard."""
+    if sharding is not None:
+        ii = np.asarray(idx, dtype=np.int64)
+
+        def put(b, lanes):
+            return _set_rows_sharded(b, ii, lanes, sharding)
+    else:
+        ii = jnp.asarray(idx, dtype=jnp.int32)
+
+        def put(b, lanes):
+            return b.at[ii].set(lanes)
     return (jax.tree.map(put, batch_state, lane_states),
             jax.tree.map(put, batch_ctx, lane_ctxs))
 

@@ -4,8 +4,8 @@ Every kernel op takes ``impl`` with the same three values:
 
 * ``"jnp"``    — the pure-jnp oracle (``ref.py``): fast on CPU, the
   byte-identical reference the Pallas path is validated against.
-* ``"pallas"`` — the Pallas TPU kernel; off-TPU it runs in interpret
-  mode so tests exercise the REAL kernel body on CPU.
+* ``"pallas"`` — the Pallas TPU kernel; on the CPU backend it runs in
+  interpret mode so tests exercise the REAL kernel body there.
 * ``"auto"``   — ``"pallas"`` on a TPU default backend, ``"jnp"``
   elsewhere (interpret mode is correct but slow, so it is never chosen
   automatically).
@@ -32,8 +32,15 @@ def resolve_impl(impl: str) -> str:
 
 
 def default_interpret() -> bool:
-    """Whether a pallas_call must run in interpret mode (no TPU)."""
-    return jax.default_backend() != "tpu"
+    """Whether a pallas_call must run in interpret mode: on the CPU
+    backend (the test suite) it must, on a TPU it must not.  Any other
+    backend has no Mosaic path, and interpreting there would hide the
+    missing device, so it raises."""
+    backend = jax.default_backend()
+    if backend in ("tpu", "cpu"):
+        return backend == "cpu"
+    raise RuntimeError(f"Pallas kernels need a TPU (or the CPU backend "
+                       f"in interpret mode); default backend is {backend!r}")
 
 
 def pad_axis(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -48,12 +55,16 @@ def pad_axis(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-# Default per-tile budget for the row-accumulate step kernels'
-# (block_n, block_w) adjacency tile.  Well under one TPU core's ~16 MiB
-# VMEM (the tile shares VMEM with the mask row, activity vectors, flag
-# outputs and the counts scratch), and large enough that every benchmark
-# bucket up to (4096, 512 words) runs as a SINGLE grid cell.
-DEFAULT_TILE_BYTES = 8 * 1024 * 1024
+# Per-tile budget for the row-accumulate step kernels' (block_n, block_w)
+# adjacency tile.  Compiled for TPU v5e, the kernel also holds a second
+# (pipelined) copy of the tile, and every (block_n, 1) int32 column — the
+# counts scratch, dense activity and flag columns, the counts output — pads
+# to 128 lanes, i.e. 512 bytes a row.  A 1 MiB tile over at most
+# ``MAX_BLOCK_ROWS`` rows keeps the worst variant (compact prefix2: seven
+# such columns) near 9 MiB, inside the compiler's 16 MiB default scoped
+# VMEM limit; the compile tests (tests/test_tpu_compile.py) hold it there.
+DEFAULT_TILE_BYTES = 1024 * 1024
+MAX_BLOCK_ROWS = 2048
 
 _LANE = 128      # TPU lane width (words per vector register row)
 
@@ -69,23 +80,16 @@ def plan_blocks(n: int, w: int, block_n: int | None = None,
 
     Explicit ``block_n``/``block_w`` are honoured (clamped to the array,
     alignment-rounded) — the test sweeps exercise fixed blockings.  The
-    ``None`` auto policy fixes the PR-5 large-n regression (BENCH_5.json:
-    fused select 1576us pallas vs 190us jnp at n=2048): the old defaults
-    split n=2048 into four full-width row blocks, so every grid cell
-    re-streamed the mask and paid per-cell launch/interpret overhead while
-    the (1,1) running argmin output was revisited four times.  The fix is
-    **width-tiled blocking**:
+    ``None`` auto policy:
 
-    * keep ALL rows resident in one row block whenever the full (n, w)
-      tile fits ``tile_bytes`` — one grid cell, one pass, counts never
-      leave VMEM;
-    * when it does not fit, tile the WIDTH first (grid = (1, w/bw)): the
-      per-row counts accumulator carries across width blocks for free,
-      while an extra ROW block would re-stream the mask and serialize the
-      argmin fold;
-    * tile rows only when a single 128-lane column stripe of all rows
-      still exceeds the budget (n > tile_bytes / 512 — far above any
-      serving bucket).
+    * one grid cell whenever all rows fit one tile (``tile_bytes`` and
+      ``MAX_BLOCK_ROWS``) — every serving bucket of the bench and large
+      suites;
+    * otherwise full-width row stripes: the mask row is the only operand
+      a row block re-reads, and the sequential grid folds the running
+      argmin / violation flag through SMEM between row blocks;
+    * width tiles (multiples of 128 lanes) only when a single ``row_mult``
+      stripe of full rows overflows the tile budget.
 
     ``row_mult`` is the row-block alignment (8 sublanes; the packed-mask
     variants need 32 so activity words align with row blocks).
@@ -95,11 +99,11 @@ def plan_blocks(n: int, w: int, block_n: int | None = None,
         bw = min(block_w or 256, max(8, w))
         return _round_up(bn, row_mult), bw
     words = tile_bytes // 4
-    bn = _round_up(n, row_mult)
-    if bn * w <= words:
-        return bn, w                        # one resident tile
-    bw = max(_LANE, (words // bn) // _LANE * _LANE)
-    if bn * bw <= words:
-        return bn, bw                       # width-tiled, rows resident
-    bn = max(row_mult, (words // bw) // row_mult * row_mult)
-    return bn, bw                           # giant n: row-tile the stripe
+    rows = max(row_mult, min(MAX_BLOCK_ROWS, words // max(w, 1))
+               // row_mult * row_mult)
+    n_pad = _round_up(n, row_mult)
+    if n_pad <= rows:
+        return n_pad, w                     # one resident tile
+    if row_mult * w <= words:
+        return rows, w                      # full-width row stripes
+    return row_mult, max(_LANE, (words // row_mult) // _LANE * _LANE)

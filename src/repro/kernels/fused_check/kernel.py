@@ -42,12 +42,12 @@ TPU mapping
 * grid = (N/BN, W/BW), W innermost: per-row partial counts accumulate in
   a VMEM scratch (BN, 1); at the last W block the flags are emitted and
   the block's Q-violation disjunction is OR-folded into the global (1,1)
-  flag output, which Pallas keeps resident across the sequential grid
-  (revisited output block), exactly like ``fused_select``.
-* |L'| arrives as a (1,1) i32 input (traced scalar, not a Python
+  flag output, a whole SMEM array resident across the sequential grid,
+  exactly like ``fused_select``.
+* |L'| arrives as a (1,1) i32 SMEM input (traced scalar, not a Python
   constant — it changes every step).
-* blocking comes from ``dispatch.plan_blocks`` (single cell / width-tiled
-  — see fused_select/kernel.py for why fixed row blocks regressed).
+* blocking comes from ``dispatch.plan_blocks`` (single cell / row
+  stripes).
 """
 from __future__ import annotations
 
@@ -58,24 +58,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fused_select.kernel import expand_act_words
+from repro.kernels.fused_select.kernel import (SMEM_SCALAR, bits_to_words,
+                                              expand_act_words)
 
 ACT_KINDS = ("dense", "packed", "prefix2")
-
-
-def pack_flag_col(flags: jax.Array, block_n: int) -> jax.Array:
-    """(BN, 1) bool flags -> (1, BN/32) uint32 words, kernel-safe.
-
-    The inverse of ``expand_act_words``, via the resident kernel's
-    reshape idiom: group 32 consecutive flags per word, shift each into
-    its lane, and lane-sum — row v lands in bit v%32 of word v//32
-    (``bitset.from_bool`` order).  BN % 32 == 0.
-    """
-    nw = block_n // 32
-    f = jnp.reshape(flags.astype(jnp.uint32), (nw, 32))
-    sh = jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
-    return jnp.reshape(jnp.sum(f << sh, axis=1, dtype=jnp.uint32,
-                               keepdims=True), (1, nw))   # (1, BN/32)
 
 
 def _kernel(*refs, block_n: int, n_wblocks: int, with_counts: bool,
@@ -83,11 +69,9 @@ def _kernel(*refs, block_n: int, n_wblocks: int, with_counts: bool,
     if act_kind == "prefix2":
         (adj_ref, mask_ref, nlp_ref, bounds_ref,
          viol_ref, full_ref, part_ref, nz_ref) = refs[:8]
-        nout = 4
     else:
         (adj_ref, mask_ref, nlp_ref, qact_ref, pact_ref,
          viol_ref, full_ref, part_ref, nz_ref) = refs[:9]
-        nout = 4
     counts_ref = refs[-2] if with_counts else None
     acc_ref = refs[-1]
     i = pl.program_id(0)
@@ -95,7 +79,7 @@ def _kernel(*refs, block_n: int, n_wblocks: int, with_counts: bool,
 
     @pl.when((i == 0) & (j == 0))
     def _init_viol():
-        viol_ref[...] = jnp.zeros_like(viol_ref)
+        viol_ref[0, 0] = jnp.int32(0)
 
     @pl.when(j == 0)
     def _init_acc():
@@ -121,21 +105,21 @@ def _kernel(*refs, block_n: int, n_wblocks: int, with_counts: bool,
             q = (rows_g < split) & (rows_g < bounds_ref[0, 0])
             p = (rows_g >= split) & (rows_g - split < bounds_ref[0, 1])
         eq = c == nlp
-        viol_ref[0, 0] = viol_ref[0, 0] | jnp.any(q & eq).astype(jnp.int32)
+        viol_ref[0, 0] = viol_ref[0, 0] | jnp.max(
+            jnp.where(q & eq, 1, 0).astype(jnp.int32))
         fullb = p & eq
         partb = p & (c > 0) & (c < nlp)
         nzb = c > 0
         if act_kind == "packed":
-            full_ref[...] = pack_flag_col(fullb, block_n)
-            part_ref[...] = pack_flag_col(partb, block_n)
-            nz_ref[...] = pack_flag_col(nzb, block_n)
+            full_ref[...] = bits_to_words(fullb, block_n // 32)
+            part_ref[...] = bits_to_words(partb, block_n // 32)
+            nz_ref[...] = bits_to_words(nzb, block_n // 32)
         else:
             full_ref[...] = fullb.astype(jnp.int32)
             part_ref[...] = partb.astype(jnp.int32)
             nz_ref[...] = nzb.astype(jnp.int32)
         if with_counts:
             counts_ref[...] = c
-    del nout
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_w",
@@ -164,7 +148,7 @@ def fused_check_pallas(adj: jax.Array, mask: jax.Array, n_mask: jax.Array,
     in_specs = [
         pl.BlockSpec((block_n, block_w), lambda i, j: (i, j)),
         pl.BlockSpec((1, block_w), lambda i, j: (0, j)),
-        pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+        SMEM_SCALAR,
     ]
     args = [adj, mask[None, :], jnp.asarray(n_mask, jnp.int32).reshape(1, 1)]
     if act_kind == "dense":
@@ -176,20 +160,21 @@ def fused_check_pallas(adj: jax.Array, mask: jax.Array, n_mask: jax.Array,
         assert block_n % 32 == 0
         assert q_act.shape == p_act.shape == (n // 32,), \
             (q_act.shape, p_act.shape, n)
-        word_spec = pl.BlockSpec((1, block_n // 32), lambda i, j: (i, 0))
+        # word rows on a squeezed leading axis (see fused_select)
+        word_spec = pl.BlockSpec((None, 1, block_n // 32),
+                                 lambda i, j: (i, 0, 0))
         in_specs += [word_spec, word_spec]
-        args += [q_act.reshape(n // block_n, block_n // 32),
-                 p_act.reshape(n // block_n, block_n // 32)]
+        args += [q_act.reshape(n // block_n, 1, block_n // 32),
+                 p_act.reshape(n // block_n, 1, block_n // 32)]
         flag_spec = word_spec
-        flag_shape = jax.ShapeDtypeStruct((n // block_n, block_n // 32),
+        flag_shape = jax.ShapeDtypeStruct((n // block_n, 1, block_n // 32),
                                           jnp.uint32)
     else:  # prefix2: one (1, 2) i32 bounds operand
-        in_specs += [pl.BlockSpec((1, 2), lambda i, j: (0, 0))]
+        in_specs += [SMEM_SCALAR]
         args += [jnp.stack([jnp.asarray(q_act, jnp.int32),
                             jnp.asarray(p_act, jnp.int32)]).reshape(1, 2)]
         flag_spec, flag_shape = col_spec, col_shape
-    out_specs = [pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-                 flag_spec, flag_spec, flag_spec]
+    out_specs = [SMEM_SCALAR, flag_spec, flag_spec, flag_spec]
     out_shape = [jax.ShapeDtypeStruct((1, 1), jnp.int32),
                  flag_shape, flag_shape, flag_shape]
     if with_counts:

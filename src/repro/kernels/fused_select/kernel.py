@@ -15,14 +15,11 @@ TPU mapping
 -----------
 * grid = (N/BN, W/BW), W innermost: per-row partial counts accumulate in a
   VMEM scratch (BN,1); at the last W block the masked block-minimum is
-  folded into the global (1,1) running (val, idx) outputs, which Pallas
-  keeps resident in VMEM across the sequential grid (revisited output
-  blocks).
+  folded into the global (1,1) running (val, idx) outputs, whole SMEM
+  arrays that stay resident across the sequential grid.
 * first-minimum-wins tie-breaking (strict <) matches jnp.argmin.
 * blocking comes from ``dispatch.plan_blocks``: one grid cell whenever the
-  (N, W) tile fits the VMEM budget, width-tiled (rows resident) otherwise
-  — the old fixed 512-row blocking re-streamed the mask and serialized the
-  argmin fold per row block, which is what regressed n=2048 in BENCH_5.
+  (N, W) tile fits its VMEM budget, full-width row stripes otherwise.
 
 Activity encodings (``act_kind``) — how "v ∈ P" reaches the kernel:
 
@@ -30,7 +27,7 @@ Activity encodings (``act_kind``) — how "v ∈ P" reaches the kernel:
 * ``"packed"`` — uint32 words, 32 activity bits per lane; the engines pass
   their pmask row directly instead of ``to_bool``-expanding it to an (N,)
   vector every step (a 32x HBM-traffic blowup on the hot operand).  The
-  kernel expands bits in VMEM via a one-hot word-select (no gather).
+  kernel expands bits in VMEM (``words_to_bits``, no gather).
 * ``"prefix"`` — a single (1, 1) int32 bound ``p``: row i is active iff
   i < p.  The compact engine's level-pointer activity, as a scalar instead
   of a materialized (N,) comparison vector.
@@ -48,20 +45,47 @@ _INF = 0x7FFFFFFF  # python int: a traced constant may not be captured
 
 ACT_KINDS = ("dense", "packed", "prefix")
 
+# (1, 1) int32 operands that the kernel reads or writes as scalars live in
+# SMEM: Mosaic has no scalar stores into VMEM, and a whole-array SMEM block
+# stays resident across the sequential grid like a revisited VMEM block.
+SMEM_SCALAR = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def words_to_bits(words: jax.Array, n: int) -> jax.Array:
+    """(1, NW) uint32 words -> (n, 1) int32 0/1 column (n <= 32 * NW):
+    row v holds bit v % 32 of word v // 32 (``bitset.to_bool`` order).
+
+    Kernel-safe without a gather: the word row is transposed into a
+    column, each word is broadcast down 32 sublanes and the (NW, 32, 1)
+    block folds into rows — shapes Mosaic lowers for 32-bit data, where a
+    (NW, 32) <-> (1, 32 NW) lane reshape is refused."""
+    nw = words.shape[1]
+    col = words.T
+    rep = jnp.reshape(jnp.broadcast_to(col[:, None, :], (nw, 32, 1)),
+                      (nw * 32, 1))
+    sh = jax.lax.broadcasted_iota(jnp.uint32, (nw * 32, 1), 0) \
+        & jnp.uint32(31)
+    return ((rep >> sh) & jnp.uint32(1)).astype(jnp.int32)[:n]
+
+
+def bits_to_words(bits: jax.Array, nw: int) -> jax.Array:
+    """(n, 1) 0/1 column -> (1, NW) uint32 words (``bitset.from_bool``),
+    the inverse of ``words_to_bits``.  The word sum runs in int32: Mosaic
+    has no unsigned reductions, and a sum of distinct powers of two is the
+    same 32 bits in either signedness."""
+    n = bits.shape[0]
+    b = bits.astype(jnp.int32)
+    if n < nw * 32:
+        b = jnp.concatenate([b, jnp.zeros((nw * 32 - n, 1), jnp.int32)],
+                            axis=0)
+    sh = jax.lax.broadcasted_iota(jnp.int32, (nw * 32, 1), 0) & 31
+    words = jnp.sum(jnp.reshape(b << sh, (nw, 32, 1)), axis=1)   # (NW, 1)
+    return jax.lax.bitcast_convert_type(words.T, jnp.uint32)
+
 
 def expand_act_words(words: jax.Array, block_n: int) -> jax.Array:
-    """(1, BN/32) uint32 activity words -> (BN, 1) bool, kernel-safe.
-
-    The resident kernel's reshape idiom instead of a gather: each word
-    fans out to 32 lanes via a broadcast shift, then a reshape lays the
-    bits down the row axis — row v reads bit v%32 of word v//32
-    (``bitset.to_bool`` order).  BN % 32 == 0.
-    """
-    nw = block_n // 32
-    w3 = jnp.reshape(words, (nw, 1))
-    sh = jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
-    bits = (w3 >> sh) & jnp.uint32(1)                    # (nw, 32)
-    return jnp.reshape(bits, (block_n, 1)) != 0
+    """(1, BN/32) uint32 activity words -> (BN, 1) bool.  BN % 32 == 0."""
+    return words_to_bits(words, block_n) > 0
 
 
 def _kernel(adj_ref, mask_ref, act_ref, val_ref, idx_ref, counts_ref, *,
@@ -71,8 +95,8 @@ def _kernel(adj_ref, mask_ref, act_ref, val_ref, idx_ref, counts_ref, *,
 
     @pl.when((i == 0) & (j == 0))
     def _init_out():
-        val_ref[...] = jnp.full_like(val_ref, _INF)
-        idx_ref[...] = jnp.full_like(idx_ref, -1)
+        val_ref[0, 0] = jnp.int32(_INF)
+        idx_ref[0, 0] = jnp.int32(-1)
 
     @pl.when(j == 0)
     def _init_counts():
@@ -92,10 +116,10 @@ def _kernel(adj_ref, mask_ref, act_ref, val_ref, idx_ref, counts_ref, *,
             rows_g = i * block_n + jax.lax.broadcasted_iota(
                 jnp.int32, (block_n, 1), 0)
             actb = rows_g < act_ref[0, 0]
-        c = jnp.where(actb, counts_ref[...], _INF)[:, 0]
+        c = jnp.where(actb, counts_ref[...], _INF)        # (BN, 1)
         bmin = jnp.min(c)
         # first minimum within the block
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_n,), 0)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (block_n, 1), 0)
         bidx = jnp.min(jnp.where(c == bmin, rows, _INF))
         better = bmin < val_ref[0, 0]
         val_ref[0, 0] = jnp.where(better, bmin, val_ref[0, 0])
@@ -127,11 +151,15 @@ def fused_select_pallas(adj: jax.Array, mask: jax.Array,
     elif act_kind == "packed":
         assert block_n % 32 == 0 and active.shape == (n // 32,), \
             (block_n, active.shape)
-        act_arg = active.reshape(n // block_n, block_n // 32)
-        act_spec = pl.BlockSpec((1, block_n // 32), lambda i, j: (i, 0))
+        # one (1, BN/32) word row per row block, on a squeezed leading
+        # axis: a (1, BN/32) block of an (N/BN, BN/32) array breaks
+        # Mosaic's (8, 128)-or-whole-dimension block rule
+        act_arg = active.reshape(n // block_n, 1, block_n // 32)
+        act_spec = pl.BlockSpec((None, 1, block_n // 32),
+                                lambda i, j: (i, 0, 0))
     else:  # prefix
         act_arg = jnp.asarray(active, jnp.int32).reshape(1, 1)
-        act_spec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+        act_spec = SMEM_SCALAR
     val, idx = pl.pallas_call(
         kern,
         grid=grid,
@@ -140,10 +168,7 @@ def fused_select_pallas(adj: jax.Array, mask: jax.Array,
             pl.BlockSpec((1, block_w), lambda i, j: (0, j)),
             act_spec,
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
+        out_specs=[SMEM_SCALAR, SMEM_SCALAR],
         out_shape=[jax.ShapeDtypeStruct((1, 1), jnp.int32),
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((block_n, 1), jnp.int32)],

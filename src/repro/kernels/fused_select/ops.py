@@ -33,8 +33,6 @@ from repro.kernels.fused_select.ref import (fused_select_packed_ref,
                                             fused_select_prefix_ref,
                                             fused_select_ref)
 
-_INF = jnp.int32(0x7FFFFFFF)
-
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_n", "block_w",
                                              "interpret"))

@@ -1,12 +1,13 @@
 """Pure-jnp oracle for fused_select (all activity encodings)."""
 from __future__ import annotations
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 
 from repro.core import bitset
 
-_INF = jnp.int32(0x7FFFFFFF)
+_INF = np.int32(0x7FFFFFFF)
 
 
 def fused_select_ref(adj: jax.Array, mask: jax.Array, active: jax.Array

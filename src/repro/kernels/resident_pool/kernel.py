@@ -37,6 +37,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.resident_step.kernel import (S_LVL, S_NTASKS, S_STEPS,
                                                 S_TPOS, SCAL_SLOTS,
@@ -56,7 +57,9 @@ def resident_pool_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
                          t_len: int, m_real: int, order_mode: str,
                          spc: int):
     """One grid cell = one lane: the single-lane resident kernel on the
-    cell's squeezed refs, plus the scoreboard write."""
+    cell's squeezed refs and its row of the SMEM scalar blocks, plus the
+    scoreboard write."""
+    lane = pl.program_id(0)
     resident_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
                     lmask_in, cstack_in, pmask_in, qmask_in, rmask_in,
                     xstack_in, outl_in, outr_in,
@@ -64,11 +67,12 @@ def resident_pool_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
                     xstack, outl, outr,
                     nu=nu, wu=wu, wv=wv, depth=depth, cap=cap,
                     t_len=t_len, m_real=m_real, order_mode=order_mode,
-                    spc=spc)
-    adv = scal[0, S_STEPS] - scal_in[0, S_STEPS]
-    done = (scal[0, S_LVL] < 0) & (scal[0, S_TPOS] >= scal[0, S_NTASKS])
-    board[0, B_DONE] = done.astype(jnp.int32)
-    board[0, B_LEFT] = spc - adv
+                    spc=spc, row=lane)
+    adv = scal[lane, S_STEPS] - scal_in[lane, S_STEPS]
+    done = (scal[lane, S_LVL] < 0) \
+        & (scal[lane, S_TPOS] >= scal[lane, S_NTASKS])
+    board[lane, B_DONE] = done.astype(jnp.int32)
+    board[lane, B_LEFT] = spc - adv
 
 
 def make_resident_pool_call(*, lanes: int, ctx_batched: bool, nu: int,
@@ -79,9 +83,12 @@ def make_resident_pool_call(*, lanes: int, ctx_batched: bool, nu: int,
     ctx_batched) identity.
 
     ``grid=(lanes,)``; per-lane state operands carry a leading lane axis
-    that the BlockSpec strips — stacks/buffers via ``None``-squeeze on
-    3-D arrays, naturally-2-D rows (scal, tasks, xstack, the context
-    vectors) via size-1 blocks the single-lane kernel already expects.
+    that the BlockSpec strips with a ``None``-squeeze — stacks/buffers
+    as (lanes, d0, d1) arrays, the per-lane rows (tasks, xstack, the
+    context vectors) as (lanes, 1, w) arrays: a (1, w) block of a
+    (lanes, w) array breaks Mosaic's (8, 128)-or-whole-dimension rule.
+    The scalar block (lanes, 16) and the scoreboard (lanes, 2) are whole
+    SMEM arrays, resident across the grid; each cell owns its row.
     ``ctx_batched`` selects per-lane context blocks (serving pools: lane
     b enumerates graph b) vs grid-constant maps over ONE shared context
     (the distributed worker layout — adjacency streamed once, reused by
@@ -92,8 +99,8 @@ def make_resident_pool_call(*, lanes: int, ctx_batched: bool, nu: int,
         t_len=t_len, m_real=m_real, order_mode=order_mode, spc=spc)
 
     def lane_row(w):
-        # (lanes, w) operand -> (1, w) block for cell l
-        return pl.BlockSpec((1, w), lambda l: (l, 0))
+        # (lanes, 1, w) operand -> squeezed (1, w) block for cell l
+        return pl.BlockSpec((None, 1, w), lambda l: (l, 0, 0))
 
     def lane_stack(d0, d1):
         # (lanes, d0, d1) operand -> squeezed (d0, d1) block for cell l
@@ -110,10 +117,10 @@ def make_resident_pool_call(*, lanes: int, ctx_batched: bool, nu: int,
                      lane_row(nu),              # root_counts
                      lane_row(wv)]              # l_root
         ctx_shapes = [((lanes, nu, wv), jnp.uint32),
-                      ((lanes, nu), jnp.int32),
-                      ((lanes, nu), jnp.int32),
-                      ((lanes, nu), jnp.int32),
-                      ((lanes, wv), jnp.uint32)]
+                      ((lanes, 1, nu), jnp.int32),
+                      ((lanes, 1, nu), jnp.int32),
+                      ((lanes, 1, nu), jnp.int32),
+                      ((lanes, 1, wv), jnp.uint32)]
     else:
         ctx_specs = [shared(nu, wv),
                      shared(1, nu), shared(1, nu), shared(1, nu),
@@ -134,24 +141,24 @@ def make_resident_pool_call(*, lanes: int, ctx_batched: bool, nu: int,
         lane_stack(cap, wu),                    # out_r
     ]
     state_shapes = [
-        ((lanes, t_len), jnp.int32),
+        ((lanes, 1, t_len), jnp.int32),
         ((lanes, depth, wv), jnp.uint32),
         ((lanes, depth, nu), jnp.int32),
         ((lanes, depth, wu), jnp.uint32),
         ((lanes, depth, wu), jnp.uint32),
         ((lanes, depth, wu), jnp.uint32),
-        ((lanes, depth), jnp.int32),
+        ((lanes, 1, depth), jnp.int32),
         ((lanes, cap, wv), jnp.uint32),
         ((lanes, cap, wu), jnp.uint32),
     ]
 
-    scal_spec = lane_row(SCAL_SLOTS)            # (lanes, 16)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)    # whole array
     scal_shape = ((lanes, SCAL_SLOTS), jnp.int32)
 
-    in_specs = [scal_spec] + ctx_specs + state_specs
+    in_specs = [smem] + ctx_specs + state_specs
     # outputs: scal + the nine mutable state blocks (tasks/ctx read-only)
     # + the scoreboard
-    out_specs = [scal_spec] + state_specs[1:] + [lane_row(BOARD_SLOTS)]
+    out_specs = [smem] + state_specs[1:] + [smem]
     out_shapes = [scal_shape] + state_shapes[1:] \
         + [((lanes, BOARD_SLOTS), jnp.int32)]
     return pl.pallas_call(

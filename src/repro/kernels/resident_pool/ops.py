@@ -96,16 +96,19 @@ def resident_pool_segment(g, cfg, s, *, start, budget,
     for slot, v in sets:
         scal = scal.at[:, slot].set(v)
     if ctx_batched:
-        ctx_args = (g.adj, g.order, g.rank, g.root_counts, g.l_root)
+        # per-lane rows ride a unit middle axis (kernel.py: lane_row)
+        ctx_args = (g.adj, g.order[:, None], g.rank[:, None],
+                    g.root_counts[:, None], g.l_root[:, None])
     else:
         ctx_args = (g.adj, g.order[None, :], g.rank[None, :],
                     g.root_counts[None, :], g.l_root[None, :])
     (scal_o, lmask, cstack, pmask, qmask, rmask, xstack2, out_l, out_r,
-     board) = call(scal, *ctx_args, s.tasks, s.lmask, s.cstack, s.pmask,
-                   s.qmask, s.rmask, s.xstack, s.out_l, s.out_r)
+     board) = call(scal, *ctx_args, s.tasks[:, None], s.lmask, s.cstack,
+                   s.pmask, s.qmask, s.rmask, s.xstack[:, None], s.out_l,
+                   s.out_r)
     s2 = s._replace(
         lmask=lmask, cstack=cstack, pmask=pmask, qmask=qmask, rmask=rmask,
-        xstack=xstack2, out_l=out_l, out_r=out_r,
+        xstack=xstack2[:, 0], out_l=out_l, out_r=out_r,
         lvl=scal_o[:, S_LVL], forced_x=scal_o[:, S_FORCED],
         tpos=scal_o[:, S_TPOS], steps=scal_o[:, S_STEPS],
         nodes=scal_o[:, S_NODES], n_max=scal_o[:, S_NMAX],

@@ -29,12 +29,23 @@ the leaf-for-leaf identity hold:
   fingerprint reproduces ``bitset.pair_checksum``'s uint32 arithmetic.
 
 Layout: masks and stacks are 2D VMEM blocks; the twelve cursor scalars
-travel in one (1, 16) int32 vector (``ops.SCAL_*`` indices; ``cs`` is
+travel in one (1, 16) int32 SMEM row (``ops.SCAL_*`` indices; ``cs`` is
 bitcast uint32<->int32).  Per-vertex context vectors (order/rank/
-root_counts) arrive as (1, N) rows.  Bit expansion and packing use
-reshape-based word/bit splits (no gathers); dynamic level/row access
-uses ``pl.ds`` ref slices.  The grid is a single cell — the whole point
-is that nothing leaves VMEM between steps.
+root_counts) arrive as (1, N) rows.  The grid is a single cell — the
+whole point is that nothing leaves VMEM between steps.
+
+What the TPU compiler (Mosaic) requires, and how the body meets it:
+
+* no scalar stores into VMEM — the cursor scalars live in SMEM;
+* no unsigned reductions — popcounts, word packing and the fingerprint
+  are reduced in int32 (two's-complement sums wrap exactly like uint32,
+  so ``cs`` is bit-identical);
+* no dynamic lane index — xstack/tasks/order reads are masked lane
+  reductions and the xstack write is a masked whole-row select; dynamic
+  access stays on the sublane (row) axis, via ``pl.ds`` ref slices;
+* no lane <-> sublane reshape of a (NW, 32) block — bit expansion and
+  packing go through row/column transposes (``words_to_bits`` /
+  ``bits_to_words``).
 """
 from __future__ import annotations
 
@@ -43,8 +54,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.fused_select.kernel import bits_to_words, words_to_bits
 
 _INF = 0x7FFFFFFF
+_SRL = jax.lax.shift_right_logical
 
 # scalar-vector slots (ops.py builds/unpacks; keep in sync)
 S_LVL, S_FORCED, S_TPOS, S_STEPS, S_NODES, S_NMAX, S_MAXFAIL, S_CS, \
@@ -52,59 +67,63 @@ S_LVL, S_FORCED, S_TPOS, S_STEPS, S_NODES, S_NMAX, S_MAXFAIL, S_CS, \
 SCAL_SLOTS = 16
 
 
+def _i32(c: int) -> int:
+    """A uint32 constant as the int32 with the same bits."""
+    return c - (1 << 32) if c >= 1 << 31 else c
+
+
 def _iota_row(n: int) -> jax.Array:
     return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
 
+def _lane_get(row: jax.Array, i: jax.Array) -> jax.Array:
+    """``row[0, i]`` of a (1, n) int32 row, 0 <= i < n, as a masked lane
+    reduction (Mosaic has no dynamic lane index)."""
+    return jnp.sum(jnp.where(_iota_row(row.shape[1]) == i, row, 0))
+
+
+def _any(b: jax.Array) -> jax.Array:
+    return jnp.max(b.astype(jnp.int32)) > 0
+
+
 def _expand_row(words: jax.Array, n: int) -> jax.Array:
     """(1, NW) uint32 packed row -> (1, n) bool (bit v of word v//32)."""
-    nw = words.shape[1]
-    w3 = jnp.reshape(words, (nw, 1))
-    sh = jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
-    bits = (w3 >> sh) & jnp.uint32(1)                 # (NW, 32)
-    return jnp.reshape(bits, (1, nw * 32))[:, :n] != 0
+    return words_to_bits(words, n).T > 0
 
 
 def _pack_row(flags: jax.Array, nw: int) -> jax.Array:
     """(1, n) bool -> (1, nw) uint32 words (bitset.from_bool)."""
-    n = flags.shape[1]
-    pad = nw * 32 - n
-    f = flags.astype(jnp.uint32)
-    if pad:
-        f = jnp.concatenate([f, jnp.zeros((1, pad), jnp.uint32)], axis=1)
-    f2 = jnp.reshape(f, (nw, 32))
-    sh = jax.lax.broadcasted_iota(jnp.uint32, (1, 32), 1)
-    return jnp.reshape(jnp.sum(f2 << sh, axis=1, dtype=jnp.uint32,
-                               keepdims=True), (1, nw))
+    return bits_to_words(flags.astype(jnp.int32).T, nw)
 
 
 def _singleton_row(i: jax.Array, nw: int) -> jax.Array:
     """(1, nw) uint32 packed {i} (empty when i < 0 — bitset.singleton)."""
-    lanes = _iota_row(nw)
     bit = jnp.uint32(1) << (i % 32).astype(jnp.uint32)
-    return jnp.where(lanes == i // 32, bit, jnp.uint32(0))
+    return jnp.where(_iota_row(nw) == i // 32, bit, jnp.uint32(0))
+
+
+def _count(words: jax.Array) -> jax.Array:
+    return jnp.sum(jax.lax.population_count(words).astype(jnp.int32))
 
 
 def _checksum_row(words: jax.Array) -> jax.Array:
-    """bitset.checksum over a (1, nw) row -> uint32 scalar."""
-    nw = words.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (1, nw), 1)
-    mult = lane * jnp.uint32(0x9E3779B9) + jnp.uint32(0x85EBCA6B)
-    h = words * mult
-    h = h ^ (h >> 15)
-    h = h * jnp.uint32(0x2545F491)
-    h = h ^ (h >> 13)
-    return jnp.sum(h, dtype=jnp.uint32)
+    """bitset.checksum over a (1, nw) row, as the int32 with its bits."""
+    h = jax.lax.bitcast_convert_type(words, jnp.int32)
+    h = h * (_iota_row(words.shape[1]) * _i32(0x9E3779B9)
+             + _i32(0x85EBCA6B))
+    h = h ^ _SRL(h, 15)
+    h = h * _i32(0x2545F491)
+    h = h ^ _SRL(h, 13)
+    return jnp.sum(h)
 
 
 def _pair_checksum_row(l_words: jax.Array, r_words: jax.Array) -> jax.Array:
-    """bitset.pair_checksum over (1, nw) rows -> uint32 scalar."""
-    hl = _checksum_row(l_words)
-    hr = _checksum_row(r_words)
-    x = hl * jnp.uint32(0x85EBCA6B) ^ (hr * jnp.uint32(0xC2B2AE35))
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x7FEB352D)
-    return x ^ (x >> 15)
+    """bitset.pair_checksum over (1, nw) rows, as an int32 scalar."""
+    x = _checksum_row(l_words) * _i32(0x85EBCA6B) \
+        ^ (_checksum_row(r_words) * _i32(0xC2B2AE35))
+    x = x ^ _SRL(x, 16)
+    x = x * _i32(0x7FEB352D)
+    return x ^ _SRL(x, 15)
 
 
 def _min_where(cond: jax.Array, idx: jax.Array) -> jax.Array:
@@ -118,9 +137,13 @@ def resident_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
                     scal, lmask, cstack, pmask, qmask, rmask,
                     xstack, outl, outr, *,
                     nu: int, wu: int, wv: int, depth: int, cap: int,
-                    t_len: int, m_real: int, order_mode: str, spc: int):
+                    t_len: int, m_real: int, order_mode: str, spc: int,
+                    row=0):
+    """``row`` is this lane's row of the SMEM scalar blocks (0 for the
+    single-lane call; the grid cell's lane in the pool kernel)."""
     # ---- state flows in through inputs, lives in the output refs -------
-    scal[...] = scal_in[...]
+    for k in range(SCAL_SLOTS):
+        scal[row, k] = scal_in[row, k]
     lmask[...] = lmask_in[...]
     cstack[...] = cstack_in[...]
     pmask[...] = pmask_in[...]
@@ -131,39 +154,39 @@ def resident_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
     outr[...] = outr_in[...]
 
     def one_step(_k, carry):
-        lvl = scal[0, S_LVL]
-        forced_x = scal[0, S_FORCED]
-        tpos = scal[0, S_TPOS]
-        steps = scal[0, S_STEPS]
-        done = (lvl < 0) & (tpos >= scal[0, S_NTASKS])
-        act = (~done) & (steps - scal[0, S_START] < scal[0, S_BUDGET])
+        lvl = scal[row, S_LVL]
+        forced_x = scal[row, S_FORCED]
+        tpos = scal[row, S_TPOS]
+        steps = scal[row, S_STEPS]
+        done = (lvl < 0) & (tpos >= scal[row, S_NTASKS])
+        act = (~done) & (steps - scal[row, S_START] < scal[row, S_BUDGET])
         lvl_safe = jnp.maximum(lvl, 0)
         pm_cur = pmask[pl.ds(lvl_safe, 1), :]            # (1, WU)
-        p_empty = jnp.sum(jax.lax.population_count(pm_cur)) == 0
+        p_empty = _count(pm_cur) == 0
         case = jnp.where(lvl < 0, 1,
                          jnp.where(p_empty & (forced_x < 0), 0, 2))
 
         @pl.when(act)
         def _count_step():
-            scal[0, S_STEPS] = steps + 1
+            scal[row, S_STEPS] = steps + 1
 
         # ---- case 0: backtrack ----------------------------------------
         @pl.when(act & (case == 0))
         def _backtrack():
             nl = lvl - 1
             safe = jnp.maximum(nl, 0)
-            x = xstack[0, safe]
+            x = _lane_get(xstack[...], safe)
             qrow = qmask[pl.ds(safe, 1), :]
             qnew = qrow | _singleton_row(jnp.maximum(x, 0), wu)
             qmask[pl.ds(safe, 1), :] = jnp.where(nl >= 0, qnew, qrow)
-            scal[0, S_LVL] = nl
+            scal[row, S_LVL] = nl
 
         # ---- case 1: init next root task ------------------------------
         @pl.when(act & (case == 1))
         def _init_task():
             ti = jnp.minimum(tpos, t_len - 1)
-            idx = tasks[0, ti]
-            x = order[0, jnp.clip(idx, 0, nu - 1)]
+            idx = _lane_get(tasks[...], ti)
+            x = _lane_get(order[...], jnp.clip(idx, 0, nu - 1))
             rk = rank[...]                               # (1, NU)
             in_p = (rk > idx) & (rk < m_real)
             in_q = rk < idx
@@ -172,9 +195,9 @@ def resident_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
             pmask[pl.ds(0, 1), :] = _pack_row(in_p, wu)
             qmask[pl.ds(0, 1), :] = _pack_row(in_q, wu)
             rmask[pl.ds(0, 1), :] = jnp.zeros((1, wu), jnp.uint32)
-            scal[0, S_LVL] = 0
-            scal[0, S_FORCED] = x
-            scal[0, S_TPOS] = tpos + 1
+            scal[row, S_LVL] = 0
+            scal[row, S_FORCED] = x
+            scal[row, S_TPOS] = tpos + 1
 
         # ---- case 2: process a candidate ------------------------------
         @pl.when(act & (case == 2))
@@ -191,9 +214,8 @@ def resident_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
                 x_sel = _min_where(masked == jnp.min(masked), col)
             elif order_mode == "deg_nocache":
                 pc = jax.lax.population_count(adj[...] & L)
-                c_all = jnp.reshape(
-                    jnp.sum(pc, axis=1, keepdims=True).astype(jnp.int32),
-                    (1, nu))
+                c_all = jnp.sum(pc.astype(jnp.int32), axis=1,
+                                keepdims=True).T         # (1, NU)
                 actb = _expand_row(pm_cur, nu)
                 masked = jnp.where(actb, c_all, _INF)
                 x_sel = _min_where(masked == jnp.min(masked), col)
@@ -206,24 +228,23 @@ def resident_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
 
             # step 2: L' = L & N(x)
             Lp = L & adj[pl.ds(jnp.clip(x, 0, nu - 1), 1), :]
-            nLp = jnp.sum(jax.lax.population_count(Lp)).astype(jnp.int32)
+            nLp = _count(Lp)
             nonempty = nLp > 0
 
             # steps 3+4: one counts pass serves the maximality check, the
             # expansion partition, the Q' filter and the cstack refill
-            c2 = jnp.reshape(
-                jnp.sum(jax.lax.population_count(adj[...] & Lp), axis=1,
-                        keepdims=True).astype(jnp.int32), (1, nu))
+            c2 = jnp.sum(jax.lax.population_count(adj[...] & Lp)
+                         .astype(jnp.int32), axis=1, keepdims=True).T
             qb = _expand_row(qmask[pl.ds(lvl_safe, 1), :], nu)
             pb = _expand_row(pm_after, nu)
             eq = c2 == nLp
-            viol = jnp.any(qb & eq) & nonempty
+            viol = _any(qb & eq) & nonempty
             fullb = pb & eq
             partb = pb & (c2 > 0) & (c2 < nLp)
             is_max = nonempty & ~viol
             Rp = rmask[pl.ds(lvl_safe, 1), :] | _singleton_row(x, wu) \
                 | _pack_row(fullb, wu)
-            has_child = is_max & jnp.any(partb)
+            has_child = is_max & _any(partb)
 
             pm_final = jnp.where(forced, jnp.zeros((1, wu), jnp.uint32),
                                  pm_after)
@@ -245,10 +266,10 @@ def resident_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
             qmask[pl.ds(q_idx, 1), :] = jnp.where(has_child, q_child, q_lvl)
             rmask[pl.ds(child, 1), :] = jnp.where(
                 has_child, Rp, rmask[pl.ds(child, 1), :])
-            xstack[:, pl.ds(lvl_safe, 1)] = jnp.where(
-                has_child, x, xstack[0, lvl_safe]).reshape(1, 1)
+            xstack[...] = jnp.where(
+                has_child & (_iota_row(depth) == lvl_safe), x, xstack[...])
 
-            out_n = scal[0, S_OUTN]
+            out_n = scal[row, S_OUTN]
             w_idx = jnp.minimum(out_n, cap - 1)
             write = is_max & (out_n < cap)
             outl[pl.ds(w_idx, 1), :] = jnp.where(
@@ -256,16 +277,16 @@ def resident_kernel(scal_in, adj, order, rank, rc, lroot, tasks,
             outr[pl.ds(w_idx, 1), :] = jnp.where(
                 write, Rp, outr[pl.ds(w_idx, 1), :])
 
-            cs = jax.lax.bitcast_convert_type(scal[0, S_CS], jnp.uint32)
-            cs = cs + jnp.where(is_max, _pair_checksum_row(Lp, Rp),
-                                jnp.uint32(0))
-            scal[0, S_CS] = jax.lax.bitcast_convert_type(cs, jnp.int32)
-            scal[0, S_LVL] = nl
-            scal[0, S_FORCED] = -1
-            scal[0, S_NODES] = scal[0, S_NODES] + 1
-            scal[0, S_NMAX] = scal[0, S_NMAX] + is_max.astype(jnp.int32)
-            scal[0, S_MAXFAIL] = scal[0, S_MAXFAIL] + viol.astype(jnp.int32)
-            scal[0, S_OUTN] = out_n + write.astype(jnp.int32)
+            # cs holds the uint32 fingerprint's bits: int32 adds wrap alike
+            scal[row, S_CS] = scal[row, S_CS] + jnp.where(
+                is_max, _pair_checksum_row(Lp, Rp), 0)
+            scal[row, S_LVL] = nl
+            scal[row, S_FORCED] = -1
+            scal[row, S_NODES] = scal[row, S_NODES] + 1
+            scal[row, S_NMAX] = scal[row, S_NMAX] + is_max.astype(jnp.int32)
+            scal[row, S_MAXFAIL] = scal[row, S_MAXFAIL] \
+                + viol.astype(jnp.int32)
+            scal[row, S_OUTN] = out_n + write.astype(jnp.int32)
 
         return carry
 
@@ -277,8 +298,8 @@ def make_resident_call(*, nu: int, wu: int, wv: int, depth: int, cap: int,
                        interpret: bool):
     """Build the pallas_call for one (cfg, steps_per_call) identity.
 
-    Single grid cell; every operand is a full-array VMEM block.  Inputs:
-    scal (1,16) i32, adj (NU,WV) u32, order/rank/root_counts (1,NU) i32,
+    Single grid cell; every operand is a full-array block.  Inputs: scal
+    (1,16) i32 in SMEM, adj (NU,WV) u32, order/rank/root_counts (1,NU) i32,
     l_root (1,WV) u32, tasks (1,T) i32, then the nine state blocks.
     Outputs: the updated scal + state blocks (tasks/ctx are read-only).
     """
@@ -289,6 +310,7 @@ def make_resident_call(*, nu: int, wu: int, wv: int, depth: int, cap: int,
     def spec(shape):
         return pl.BlockSpec(shape, lambda: tuple(0 for _ in shape))
 
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     in_shapes = [
         ((1, SCAL_SLOTS), jnp.int32),    # scal
         ((nu, wv), jnp.uint32),          # adj
@@ -310,8 +332,8 @@ def make_resident_call(*, nu: int, wu: int, wv: int, depth: int, cap: int,
     return pl.pallas_call(
         kern,
         grid=(),
-        in_specs=[spec(s) for s, _ in in_shapes],
-        out_specs=[spec(s) for s, _ in out_shapes],
+        in_specs=[smem] + [spec(s) for s, _ in in_shapes[1:]],
+        out_specs=[smem] + [spec(s) for s, _ in out_shapes[1:]],
         out_shape=[jax.ShapeDtypeStruct(s, d) for s, d in out_shapes],
         interpret=interpret,
     )

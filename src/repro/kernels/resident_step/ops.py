@@ -25,16 +25,27 @@ from repro.kernels.resident_step.kernel import (
     S_BUDGET, S_CS, S_FORCED, S_LVL, S_MAXFAIL, S_NMAX, S_NODES, S_NTASKS,
     S_OUTN, S_START, S_STEPS, S_TPOS, SCAL_SLOTS, make_resident_call)
 
-# VMEM budget for (context + state) blocks, deliberately conservative:
-# the compiled kernel also holds the (1, NU) expansion intermediates and
-# Mosaic's own spill headroom inside ~16 MiB of VMEM.
-RESIDENT_STATE_BYTES = 6 * 1024 * 1024
+# VMEM budget for (context + state) blocks, in the padded bytes that
+# ``resident_state_bytes`` counts: the TPU compiler's default scoped VMEM
+# limit on v5e.  Compiled for v5e, single-lane and pool kernels both fit
+# that limit up to a padded charge of about 18 MiB (n_u = 1280 buckets)
+# and are refused from about 21 MiB (n_u = 1408), so the gate keeps a
+# margin for Mosaic's own scratch; tests/test_tpu_compile.py compiles the
+# largest bench/large bucket the gate admits.
+RESIDENT_STATE_BYTES = 16 * 1024 * 1024
+
+
+def _block_bytes(rows: int, cols: int) -> int:
+    """Bytes of a (rows, cols) 32-bit VMEM block in (8, 128) tiles; a
+    (1, cols) row is laid out in (1, 128) tiles."""
+    r = rows if rows == 1 else -(-rows // 8) * 8
+    return 4 * r * (-(-cols // 128) * 128)
 
 
 def resident_state_bytes(cfg, t_len: int | None = None,
                          lanes: int = 1) -> int:
     """Bytes of VMEM the resident kernel pins for ``cfg`` (context +
-    state + outputs; 4-byte words throughout).
+    state + outputs, each block padded to the TPU's tiles).
 
     ``lanes`` scales the per-lane state/output terms for paths that hold
     several lanes' residency at once: ``run_batch``'s vmap path launches
@@ -44,11 +55,16 @@ def resident_state_bytes(cfg, t_len: int | None = None,
     either way.
     """
     t = cfg.n_u if t_len is None else t_len
-    ctx = cfg.n_u * cfg.wv + 3 * cfg.n_u + cfg.wv + t
-    state = cfg.depth * (cfg.wv + cfg.n_u + 3 * cfg.wu + 1)
-    out = cfg.collect_cap * (cfg.wv + cfg.wu) + SCAL_SLOTS
-    # state/out double-buffered per resident lane
-    return 4 * (ctx + lanes * (2 * state + 2 * out))
+    ctx = _block_bytes(cfg.n_u, cfg.wv) + 3 * _block_bytes(1, cfg.n_u) \
+        + _block_bytes(1, cfg.wv)
+    state = _block_bytes(cfg.depth, cfg.wv) \
+        + _block_bytes(cfg.depth, cfg.n_u) \
+        + 3 * _block_bytes(cfg.depth, cfg.wu) \
+        + _block_bytes(1, cfg.depth) + _block_bytes(1, t)
+    out = _block_bytes(cfg.collect_cap, cfg.wv) \
+        + _block_bytes(cfg.collect_cap, cfg.wu)
+    # state/out held as input and output blocks per resident lane
+    return ctx + lanes * 2 * (state + out)
 
 
 def resident_supported(cfg, t_len: int | None = None,

@@ -27,6 +27,7 @@ from repro.api import (MBEClient, MBEOptions, get_engine, imbalance,
                        unipartite_graph)
 from repro.configs.cumbe import SMOKE
 from repro.data import dataset_suite, load_konect
+from repro.launch.compile_cache import use_compile_cache
 
 # per-suite default dataset: the bench suite keeps the historical
 # marvel-like default; the test suite (CI smoke) uses its tiny power-law
@@ -75,6 +76,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--order", default="deg", choices=["deg", "input"])
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.file:
         g = load_konect(args.file)

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 
 from repro import configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as M
 from repro.models.config import ShapeSpec
@@ -287,6 +288,7 @@ def serve(argv=None) -> dict:
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.mbe:
         return serve_mbe(args)

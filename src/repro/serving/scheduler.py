@@ -102,6 +102,7 @@ from repro.core.results import EngineResult, MBEResult  # noqa: F401  (MBEResult
 #                             re-exported: the historical import surface of
 #                             this module, now defined with the rest of the
 #                             result schema in repro.core.results)
+from repro.kernels.dispatch import resolve_impl
 from repro.serving.buckets import (BucketPolicy, BucketSpec, plan_bucket,
                                    plan_route)
 from repro.serving.cache import ExecutableCache
@@ -1315,7 +1316,9 @@ class MBEServer:
                     # scheduler-level and kernel-level wins are separable
                     # in one stats read (benchmarks/serving.py reports
                     # steps/s alongside occupancy from these)
-                    kernel_impl=self.kernel_impl,
+                    # the step-kernel path that ran ('auto' resolved
+                    # against the backend: 'pallas' on a TPU)
+                    kernel_impl=resolve_impl(self.kernel_impl),
                     steps_per_call=self.policy.steps_per_call,
                     steps_per_poll=(self._busy_steps / self._n_rounds
                                     if self._n_rounds else 0.0),

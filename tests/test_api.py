@@ -14,6 +14,9 @@ MBEFuture semantics, and the engine registry.
 * future semantics: done()/result(timeout)/cancel(), unknown rids.
 """
 import functools
+import os
+import subprocess
+import sys
 
 import pytest
 from _graphs import random_graph
@@ -49,6 +52,40 @@ def test_public_import_surface():
     for name in repro.__all__:
         assert getattr(repro, name, None) is not None, name
     assert isinstance(repro.__version__, str) and repro.__version__
+
+
+def test_import_starts_no_backend():
+    """Importing ``repro`` (and the oracle module the ParMBE pool's
+    spawned workers import) must not start a JAX backend: on a TPU host
+    that would claim the chip for every such process."""
+    code = ("import repro, repro.baselines.mbea, repro.serving\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+
+
+def test_compile_cache_dir_from_env_else_checkout(monkeypatch, tmp_path):
+    """The launchers' persistent compile cache: $JAX_COMPILATION_CACHE_DIR
+    when set, else the fixed <checkout>/.jax_cache — never a per-run
+    name, which would never hit."""
+    import jax
+    from repro.launch.compile_cache import CHECKOUT, use_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = os.path.join(CHECKOUT, ".jax_cache")
+        assert use_compile_cache() == fixed
+        assert jax.config.jax_compilation_cache_dir == fixed
+        assert os.path.isfile(os.path.join(CHECKOUT, "chip_smoke.py"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 def test_engine_registry():

@@ -117,6 +117,31 @@ def test_replace_lanes_batched_scatter_matches_sequential():
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_sharded_surgery_matches_unsharded_and_keeps_placement():
+    """With a mesh ``sharding`` the surgery runs shard by shard: the rows
+    equal the single-device scatter's, and every leaf keeps the pool's
+    placement (no reshard before the next round)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    graphs = [random_graph(9 + s, 20 + s, 0.25, 40 + s, canonical=True)
+              for s in range(4)]
+    cfg = _bucketed_cfg(graphs)
+    state, ctx = _stack_lanes(cfg, graphs)
+    state = _run_rounds(cfg, state, ctx, max_steps=5)
+    sh = NamedSharding(Mesh(np.array(jax.devices()), ("lanes",)), P("lanes"))
+    new_graphs = [random_graph(10, 21, 0.3, 60 + s, canonical=True)
+                  for s in range(2)]
+    ns = jax.tree.map(lambda *xs: jnp.stack(xs),
+                      *[fresh_lane_state(cfg, g.n_u) for g in new_graphs])
+    nc = jax.tree.map(lambda *xs: jnp.stack(xs),
+                      *[ed.make_context(g, cfg) for g in new_graphs])
+    want = ed.replace_lanes(state, ctx, [3, 0], ns, nc)
+    got = ed.replace_lanes(jax.device_put(state, sh), jax.device_put(ctx, sh),
+                           [3, 0], ns, nc, sharding=sh)
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert b.sharding == sh
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_dummy_eviction_surgery_is_local():
     """Evicting a lane to the dummy (idle, born-done) state must not
     perturb any other lane."""
