@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device
+(``bench/trace.py``), in %."""
+
+
+def read(run):
+    t = run["trace"]
+    return None if t is None else 100 * t.idle_share
