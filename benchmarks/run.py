@@ -5,14 +5,12 @@
   python -m benchmarks.run --scale test    # quick CI pass
 
 Outputs one CSV per harness under benchmarks/artifacts/ plus a stdout
-summary. The roofline harness needs dry-run artifacts
-(python -m repro.launch.dryrun) and is skipped when they are missing.
+summary.
 """
 from __future__ import annotations
 
 import argparse
 import csv
-import glob
 import os
 import time
 
@@ -33,13 +31,12 @@ def _write_csv(name: str, rows: list[dict]) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    choices=["table1", "workload", "ablation", "roofline",
-                             "serving"])
+                    choices=["table1", "workload", "ablation", "serving"])
     ap.add_argument("--scale", default="bench",
                     choices=["test", "bench", "large"])
     args = ap.parse_args()
     todo = [args.only] if args.only else [
-        "table1", "ablation", "workload", "roofline", "serving"]
+        "table1", "ablation", "workload", "serving"]
 
     for name in todo:
         t0 = time.time()
@@ -53,14 +50,6 @@ def main() -> int:
         elif name == "workload":
             from benchmarks import workload
             _write_csv("workload", workload.run())
-        elif name == "roofline":
-            from benchmarks import roofline
-            if not glob.glob(os.path.join(ART, "dryrun", "*.json")):
-                print("(skipped: no dry-run artifacts; "
-                      "run python -m repro.launch.dryrun first)")
-                continue
-            rows = roofline.run()
-            _write_csv("roofline", rows)
         elif name == "serving":
             from benchmarks import serving
             n = 32 if args.scale != "test" else 8

@@ -188,6 +188,7 @@ def fused_check_pallas(adj: jax.Array, mask: jax.Array, n_mask: jax.Array,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_n, 1), jnp.int32)],
         interpret=interpret,
+        name="fused_check",
     )(*args)
     viol = out[0][0, 0]
     if act_kind == "packed":

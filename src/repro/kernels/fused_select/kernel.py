@@ -173,5 +173,6 @@ def fused_select_pallas(adj: jax.Array, mask: jax.Array,
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((block_n, 1), jnp.int32)],
         interpret=interpret,
+        name="fused_select",
     )(adj, mask[None, :], act_arg)
     return idx[0, 0], val[0, 0]

@@ -63,5 +63,6 @@ def intersect_count_pallas(adj: jax.Array, mask: jax.Array, *,
         out_specs=pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
         interpret=interpret,
+        name="intersect_count",
     )(adj, mask[None, :])
     return out[:, 0]

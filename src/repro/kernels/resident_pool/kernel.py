@@ -168,4 +168,5 @@ def make_resident_pool_call(*, lanes: int, ctx_batched: bool, nu: int,
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct(s, d) for s, d in out_shapes],
         interpret=interpret,
+        name="resident_pool",
     )

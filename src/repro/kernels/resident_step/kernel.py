@@ -336,4 +336,5 @@ def make_resident_call(*, nu: int, wu: int, wv: int, depth: int, cap: int,
         out_specs=[smem] + [spec(s) for s, _ in out_shapes[1:]],
         out_shape=[jax.ShapeDtypeStruct(s, d) for s, d in out_shapes],
         interpret=interpret,
+        name="resident_step",
     )

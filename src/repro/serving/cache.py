@@ -89,7 +89,8 @@ class CacheEntry:
         if self._compiled is None:
             t0 = time.perf_counter()
             try:
-                compiled = self._jit.lower(ctx, s).compile()
+                with jax.profiler.TraceAnnotation("mbe.compile"):
+                    compiled = self._jit.lower(ctx, s).compile()
             except Exception:
                 if self._on_failed is not None:
                     self._on_failed(self)
@@ -105,10 +106,13 @@ class CacheEntry:
         needs: returns ``(out, wall_s, compile_s)`` where ``wall_s`` is
         the full blocked wall time and ``compile_s`` is the XLA compile
         charged to THIS call (0.0 whenever the entry was already
-        compiled — compilation is never billed twice)."""
+        compiled — compilation is never billed twice).  The blocked
+        interval is the host span ``mbe.round.wait`` (``mbe.compile``
+        nested inside on a first call)."""
         was_compiled = self.compiled
         t0 = time.perf_counter()
-        out = jax.block_until_ready(self(ctx, s))
+        with jax.profiler.TraceAnnotation("mbe.round.wait"):
+            out = jax.block_until_ready(self(ctx, s))
         wall = time.perf_counter() - t0
         return out, wall, (0.0 if was_compiled else self.compile_s)
 

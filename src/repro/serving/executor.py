@@ -131,12 +131,14 @@ class Executor(abc.ABC):
                 states: list[ed.DenseState],
                 ctxs: list[ed.GraphContext]) -> None:
         """Place fresh single-lane (state, ctx) pairs into rows ``idx``
-        (one batched scatter, re-pinned to the backend's sharding)."""
-        pool.state, pool.ctx = ed.replace_lanes(
-            pool.state, pool.ctx, idx,
-            jax.tree.map(lambda *xs: jnp.stack(xs), *states),
-            jax.tree.map(lambda *xs: jnp.stack(xs), *ctxs),
-            sharding=self._pool_sharding())
+        (one batched scatter, re-pinned to the backend's sharding; the
+        host span ``mbe.install``)."""
+        with jax.profiler.TraceAnnotation("mbe.install"):
+            pool.state, pool.ctx = ed.replace_lanes(
+                pool.state, pool.ctx, idx,
+                jax.tree.map(lambda *xs: jnp.stack(xs), *states),
+                jax.tree.map(lambda *xs: jnp.stack(xs), *ctxs),
+                sharding=self._pool_sharding())
 
     def migrate(self, old: LanePool, new: LanePool,
                 live_idx: list[int]) -> None:

@@ -87,13 +87,20 @@ compilation).  Pool-level occupancy is tracked in steps: ``busy_steps``
 (per-lane engine steps actually advanced) over ``total_lane_steps``
 (lanes x the per-round critical path) — the refill mechanism's win shows
 up as this ratio, and the big-graph lane's rounds enter the same ledger.
+Host time is split by phase: each poll, refill and demux is a host span
+on the profiler's clock (``mbe.poll``/``mbe.refill``/``mbe.demux``) whose
+wall time also accumulates into ``stats()`` (``poll_s``/``refill_s``/
+``demux_s``), beside ``exec_s``, the time blocked on round executables
+(DESIGN.md §12).
 """
 from __future__ import annotations
 
 import bisect
+import contextlib
 import dataclasses
 import time
 
+import jax
 import numpy as np
 
 from repro.core.engine import Engine, get_engine
@@ -130,6 +137,23 @@ def imbalance(per_worker) -> float:
     return float(a.max()) / mean if mean > 0 else 1.0
 
 
+@contextlib.contextmanager
+def _phase(name: str, owner=None, counter: str | None = None):
+    """A host span ``name`` on the profiler's clock (the clock of the
+    device planes in the same trace; about a microsecond with no profiler
+    running).  With ``counter``, the span's ``perf_counter`` wall time is
+    also added to ``owner.<counter>``, one of the ``stats()`` time
+    counters.  Names are constants: a span never formats its name."""
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield
+    finally:
+        if counter is not None:
+            setattr(owner, counter,
+                    getattr(owner, counter) + time.perf_counter() - t0)
+
+
 # The stats() contract: every key the dict carries and its type, for all
 # executors (local / sharded) and all routes (lane pool / big graph) and
 # every registered engine.  tests/test_stats_contract.py asserts a served
@@ -147,7 +171,8 @@ STATS_SCHEMA: dict[str, type | tuple] = dict(
     big_busy_per_worker=list, big_imbalance=float,
     failed=int, step_capped=int, retries=int, faults_injected=int,
     checkpoints=int, quarantined=int, failovers=int,
-    hits=int, misses=int, entries=int, evictions=int)
+    hits=int, misses=int, entries=int, evictions=int,
+    poll_s=float, refill_s=float, demux_s=float, exec_s=float)
 
 # Monotonic counters (reset by ``MBEServer.reset_stats``); everything
 # else in STATS_SCHEMA is a gauge or a configuration echo.
@@ -158,7 +183,8 @@ MONOTONIC_STATS = frozenset((
     "rejected_backpressure", "rejected_fairness",
     "failed", "step_capped", "retries", "faults_injected",
     "checkpoints", "quarantined", "failovers",
-    "hits", "misses", "evictions"))
+    "hits", "misses", "evictions",
+    "poll_s", "refill_s", "demux_s", "exec_s"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -290,7 +316,7 @@ class _LanePool:
         server._n_rounds += 1
         server._busy_steps += busy
         server._total_lane_steps += self.B * crit
-        server._exec_wall_s += exec_s
+        server._exec_s += exec_s
         # launch accounting: the round's critical path ran ceil(crit/spc)
         # compiled segments, each costing launches_per_segment kernel
         # dispatches (1 per pool on the multi-lane path, B on vmap)
@@ -459,7 +485,11 @@ class MBEServer:
         self._n_pad_lanes = 0
         self._busy_steps = 0
         self._total_lane_steps = 0
-        self._exec_wall_s = 0.0
+        self._exec_s = 0.0          # round executables' wall - compile
+        # host time of the poll phases, fed by their mbe.* spans
+        self._poll_s = 0.0
+        self._refill_s = 0.0
+        self._demux_s = 0.0
         self._n_launches = 0
         self._rebalanced_steps = 0
         self._n_cancelled = 0
@@ -756,7 +786,7 @@ class MBEServer:
         self._n_rounds += 1
         self._busy_steps += busy
         self._total_lane_steps += slot.lane.n_workers * crit
-        self._exec_wall_s += exec_s
+        self._exec_s += exec_s
         # launch accounting mirrors the pool rounds: inside shard_map
         # each device advances wpd workers, in ONE pool launch per
         # segment when the multi-lane kernel is active, else wpd
@@ -1205,14 +1235,18 @@ class MBEServer:
         for bucket in self._buckets_with_work():
             queue = self._queues.setdefault(bucket, _PendingQueue())
             pool = self._ensure_pool(bucket)
-            placed = pool.refill(queue, self)
+            with _phase("mbe.refill", self, "_refill_s"):
+                placed = pool.refill(queue, self)
             self._n_lanes += placed
             if pool.n_live() == 0:
                 del self._pools[bucket]
                 continue
             self._n_pad_lanes += pool.B - pool.n_live()
-            if pool.run_round(self):
-                self._completed.update(pool.demux(self))
+            with _phase("mbe.round"):
+                ran = pool.run_round(self)
+            if ran:
+                with _phase("mbe.demux", self, "_demux_s"):
+                    self._completed.update(pool.demux(self))
                 pool.enforce_step_cap(self)
             if pool.n_live() == 0 and not queue:
                 del self._pools[bucket]    # fully drained; next wave may
@@ -1222,7 +1256,7 @@ class MBEServer:
             self.trace.poll(
                 busy_steps=self._busy_steps,
                 total_lane_steps=self._total_lane_steps,
-                exec_s=self._exec_wall_s,
+                exec_s=self._exec_s,
                 pending=(sum(len(q) for q in self._queues.values())
                          + len(self._big_queue)),
                 in_flight=(sum(p.n_live() for p in self._pools.values())
@@ -1274,15 +1308,17 @@ class MBEServer:
     def poll(self) -> dict[int, EngineResult]:
         """One scheduling round; returns {rid: result} for requests that
         finished (including any stashed by an earlier round that raised)."""
-        self._poll_once()
-        return self._take_completed()
+        with _phase("mbe.poll", self, "_poll_s"):
+            self._poll_once()
+            return self._take_completed()
 
     def drain(self) -> dict[int, EngineResult]:
         """Serve everything pending; returns {rid: result}.  After a
         step-cap RuntimeError, calling ``drain`` again serves the
         surviving requests and returns any stashed results."""
         while self._has_work():
-            self._poll_once()
+            with _phase("mbe.poll", self, "_poll_s"):
+                self._poll_once()
         return self._take_completed()
 
     def flush(self) -> dict[int, EngineResult]:
@@ -1371,6 +1407,12 @@ class MBEServer:
                     # no big request ran)
                     big_imbalance=(1.0 if busy_pw is None
                                    else imbalance(busy_pw)),
+                    # host time in seconds: whole polls, and the lane
+                    # pools' refill and demux phases inside them; exec_s
+                    # is round-executable wall minus compile (the time
+                    # the host blocks on a round, pools and big lane)
+                    poll_s=self._poll_s, refill_s=self._refill_s,
+                    demux_s=self._demux_s, exec_s=self._exec_s,
                     **self.cache.stats())
 
     def reset_stats(self) -> None:
@@ -1387,9 +1429,11 @@ class MBEServer:
         ``shed``, ``rejected_backpressure``, ``rejected_fairness``,
         ``failed``, ``step_capped``, ``retries``, ``faults_injected``,
         ``checkpoints``, ``quarantined``, ``failovers``,
-        ``per_tenant``, ``big_busy_per_worker``, ``big_imbalance``, and
-        the cache counters ``hits``/``misses``/``evictions`` (so the
-        miss count stays an honest per-phase compile count).
+        ``per_tenant``, ``big_busy_per_worker``, ``big_imbalance``,
+        the host time counters ``poll_s``, ``refill_s``, ``demux_s``,
+        ``exec_s``, and the cache counters ``hits``/``misses``/
+        ``evictions`` (so the miss count stays an honest per-phase
+        compile count).
 
         Gauges are NOT touched: ``pending``, ``in_flight``, ``entries``
         (live cache entries), and the configuration echoes
@@ -1402,7 +1446,10 @@ class MBEServer:
         self._n_pad_lanes = 0
         self._busy_steps = 0
         self._total_lane_steps = 0
-        self._exec_wall_s = 0.0
+        self._exec_s = 0.0
+        self._poll_s = 0.0
+        self._refill_s = 0.0
+        self._demux_s = 0.0
         self._n_launches = 0
         self._rebalanced_steps = 0
         self._n_cancelled = 0

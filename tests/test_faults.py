@@ -221,11 +221,16 @@ def test_transient_streak_exonerates_all_suspects():
 
 def test_off_by_default_is_byte_identical():
     """No plan, no policy: stats() and payloads identical across two
-    fresh servers, and the whole fault ledger reads zero."""
+    fresh servers, and the whole fault ledger reads zero.  The host time
+    counters (``poll_s`` etc.) are wall clock, so they alone may differ."""
     gs = _graphs("dense")
     srv1, got1 = _serve(gs)
     srv2, got2 = _serve(gs)
-    assert srv1.stats() == srv2.stats()
+    clock = {"poll_s", "refill_s", "demux_s", "exec_s"}
+
+    def counted(srv):
+        return {k: v for k, v in srv.stats().items() if k not in clock}
+    assert counted(srv1) == counted(srv2)
     assert {r: _payload(v) for r, v in got1.items()} \
         == {r: _payload(v) for r, v in got2.items()}
     for key in ("retries", "faults_injected", "checkpoints",
