@@ -5,7 +5,8 @@ executable cache, executors, the continuous-batching scheduler and the
 big-graph work-stealing lane all drive engines exclusively through this
 module's ``Engine`` ABC.  An engine declares:
 
-* **constructors** — ``make_context`` (device-resident graph data),
+* **constructors** — ``make_context`` (device-resident graph data;
+  ``host_context`` builds the same pytree as NumPy arrays),
   ``init_state`` (the worker-state pytree), ``dummy_context`` (idle
   lanes), ``config`` (bucket-shaped ``EngineConfig``, including any
   engine-specific parameters such as the count engine's ``(p, q)``);
@@ -101,6 +102,13 @@ class Engine(abc.ABC):
     def make_context(self, g: BipartiteGraph, cfg: EngineConfig):
         """Device-resident graph data (adjacency + orderings)."""
 
+    def host_context(self, g: BipartiteGraph, cfg: EngineConfig):
+        """``make_context`` as a pytree of NumPy arrays: the serving
+        refill builds lanes' contexts on the host and sends them to the
+        device in one transfer.  This default builds on the device and
+        copies back; engines that build it in NumPy override it."""
+        return jax.device_get(self.make_context(g, cfg))
+
     @abc.abstractmethod
     def init_state(self, cfg: EngineConfig, tasks: np.ndarray):
         """Fresh worker state owning the given root-task list."""
@@ -138,6 +146,21 @@ class Engine(abc.ABC):
         pad = np.full(cfg.n_u, -1, np.int32)
         pad[:n_tasks] = np.arange(n_tasks, dtype=np.int32)
         return s._replace(tasks=jnp.asarray(pad))
+
+    def fresh_lane_rows(self, cfg: EngineConfig, n_tasks: jax.Array):
+        """Traceable ``fresh_lane_state`` over a leading lane axis: row
+        ``i`` equals ``fresh_lane_state(cfg, n_tasks[i])``.  That is the
+        born-done template ``fresh_lane_state(cfg, 0)`` with the task
+        queue and its length set, so the install executable builds fresh
+        lanes on the device.  An engine whose fresh state depends on the
+        task count in any other field overrides this."""
+        rows = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (n_tasks.shape[0], *x.shape)),
+            self.fresh_lane_state(cfg, 0))
+        t = jnp.arange(cfg.n_u, dtype=jnp.int32)
+        n = n_tasks.astype(jnp.int32)
+        return rows._replace(tasks=jnp.where(t < n[:, None], t, -1),
+                             n_tasks=n)
 
     # -- execution ------------------------------------------------------
     @abc.abstractmethod
@@ -306,6 +329,9 @@ class DenseEngine(Engine):
     def make_context(self, g, cfg):
         return ed.make_context(g, cfg)
 
+    def host_context(self, g, cfg):
+        return ed.host_context(g, cfg)
+
     def init_state(self, cfg, tasks):
         return ed.init_state(cfg, tasks)
 
@@ -339,6 +365,9 @@ class CompactEngine(Engine):
 
     def make_context(self, g, cfg):
         return ec.make_context(g, cfg)
+
+    def host_context(self, g, cfg):
+        return ec.host_context(g, cfg)
 
     def init_state(self, cfg, tasks):
         return ec.init_state(cfg, tasks)

@@ -96,7 +96,9 @@ class CompactState(NamedTuple):
     out_r: jax.Array
 
 
-def make_context(g: BipartiteGraph, cfg: EngineConfig) -> CompactContext:
+def host_context(g: BipartiteGraph, cfg: EngineConfig) -> CompactContext:
+    """``make_context`` as NumPy arrays (see
+    ``engine_dense.host_context``)."""
     assert g.n_u <= cfg.n_u and g.n_v <= cfg.n_v
     # Zero-extended word copy: packed rows are prefix-compatible under
     # padding (bit v stays at word v//32), so no edge-list round-trip —
@@ -123,10 +125,13 @@ def make_context(g: BipartiteGraph, cfg: EngineConfig) -> CompactContext:
     l_root = np.zeros(cfg.wv, dtype=np.uint32)
     fm = bitset.full_mask(g.n_v)
     l_root[: fm.shape[0]] = fm
-    return CompactContext(
-        adj=jnp.asarray(adj), order=jnp.asarray(order),
-        p_static=jnp.asarray(p_static), lk_static=jnp.asarray(lk_static),
-        q_static=jnp.asarray(q_static), l_root=jnp.asarray(l_root))
+    return CompactContext(adj=adj, order=order, p_static=p_static,
+                          lk_static=lk_static, q_static=q_static,
+                          l_root=l_root)
+
+
+def make_context(g: BipartiteGraph, cfg: EngineConfig) -> CompactContext:
+    return jax.tree.map(jnp.asarray, host_context(g, cfg))
 
 
 def init_state(cfg: EngineConfig, tasks: np.ndarray) -> CompactState:

@@ -183,7 +183,10 @@ class DenseState(NamedTuple):
 # host-side setup
 # ---------------------------------------------------------------------------
 
-def make_context(g: BipartiteGraph, cfg: EngineConfig) -> GraphContext:
+def host_context(g: BipartiteGraph, cfg: EngineConfig) -> GraphContext:
+    """``make_context`` as NumPy arrays, built on the host with no device
+    dispatch (the serving refill stacks these rows and sends them in one
+    transfer)."""
     assert g.n_u <= cfg.n_u and g.n_v <= cfg.n_v
     # Packed rows are PREFIX-COMPATIBLE under padding: bit v lives at word
     # v//32 regardless of the total word count, so padding n_v only appends
@@ -211,9 +214,12 @@ def make_context(g: BipartiteGraph, cfg: EngineConfig) -> GraphContext:
     l_root[: fm.shape[0]] = fm
     rc = np.zeros(cfg.n_u, dtype=np.int32)
     rc[: g.n_u] = deg.astype(np.int32)
-    return GraphContext(adj=jnp.asarray(adj), order=jnp.asarray(order),
-                        rank=jnp.asarray(rank), l_root=jnp.asarray(l_root),
-                        root_counts=jnp.asarray(rc))
+    return GraphContext(adj=adj, order=order, rank=rank, l_root=l_root,
+                        root_counts=rc)
+
+
+def make_context(g: BipartiteGraph, cfg: EngineConfig) -> GraphContext:
+    return jax.tree.map(jnp.asarray, host_context(g, cfg))
 
 
 def init_state(cfg: EngineConfig, tasks: np.ndarray) -> DenseState:
@@ -701,7 +707,13 @@ def replace_lanes(batch_state: DenseState, batch_ctx: GraphContext,
                   sharding=None) -> tuple[DenseState, GraphContext]:
     """Vectorized ``replace_lane``: install ``len(idx)`` lanes (leading
     axis of every ``lane_states``/``lane_ctxs`` leaf) with ONE scatter per
-    leaf, instead of one full-batch copy per lane — the refill hot path.
+    leaf, instead of one full-batch copy per lane.
+
+    The serving refill places fresh lanes of an unsharded pool through
+    the pool's install executable instead (``Executor.install``: one
+    dispatch, buffers donated).  This surgery takes the lanes that
+    executable cannot: lanes of a sharded pool, lanes resumed from a host
+    checkpoint (a whole state, not a fresh one), and eviction.
 
     ``sharding`` (the pool's ``jax.sharding.Sharding``) switches to
     shard-local surgery (``_set_rows_sharded``): every output leaf keeps

@@ -19,10 +19,12 @@ Entry flavours sharing the cache:
   refill them between rounds.  Because the budget is part of the key, a
   continuous stream costs exactly ONE round-mode compile per
   ``(bucket, batch)`` pair, no matter how many rounds it runs.
-* **backend entries** (via ``get_entry``) wrap an arbitrary jitted round
-  function — the ``ShardedExecutor``'s mesh-placed ``shard_map`` round and
-  the big-graph lane's work-stealing round.  AOT compile timing works the
-  same way for every backend: the entry times its own ``lower().compile()``.
+* **backend entries** (via ``get_entry``) wrap an arbitrary jitted
+  function — the ``ShardedExecutor``'s mesh-placed ``shard_map`` round,
+  the big-graph lane's work-stealing round, and each local pool's
+  fixed-shape install executable (``Executor.install``).  AOT compile
+  timing works the same way for every backend: the entry times its own
+  ``lower().compile()``.
 
 Entries also time their own XLA compilation: the first call AOT-lowers and
 compiles (``jit.lower(...).compile()``) with ``time.perf_counter`` around
@@ -85,12 +87,12 @@ class CacheEntry:
     def compiled(self) -> bool:
         return self._compiled is not None
 
-    def __call__(self, ctx: ed.GraphContext, s: ed.DenseState):
+    def __call__(self, *args):
         if self._compiled is None:
             t0 = time.perf_counter()
             try:
                 with jax.profiler.TraceAnnotation("mbe.compile"):
-                    compiled = self._jit.lower(ctx, s).compile()
+                    compiled = self._jit.lower(*args).compile()
             except Exception:
                 if self._on_failed is not None:
                     self._on_failed(self)
@@ -99,7 +101,7 @@ class CacheEntry:
             self._compiled = compiled
             if self._on_compiled is not None:
                 self._on_compiled(self)
-        return self._compiled(ctx, s)
+        return self._compiled(*args)
 
     def timed_call(self, ctx: ed.GraphContext, s: ed.DenseState):
         """Blocking call with the round-accounting split every backend
@@ -134,9 +136,10 @@ class ExecutableCache:
     # ------------------------------------------------------------------
     def get_entry(self, key, build: Callable[[], object]) -> CacheEntry:
         """Generic keyed lookup: on miss, ``build()`` must return a jitted
-        ``(ctx, state) -> ...`` function which is wrapped in a lazily
-        AOT-compiled ``CacheEntry``.  Executors use this to register their
-        backend-specific round functions under backend-qualified keys.
+        function (a round's ``(ctx, state) -> ...``, or a pool's install
+        executable) which is wrapped in a lazily AOT-compiled
+        ``CacheEntry``.  Executors use this to register their
+        backend-specific executables under backend-qualified keys.
 
         Compile-failure safety: the entry is inserted (and the miss
         counted) here, but if its first AOT compile RAISES the entry is

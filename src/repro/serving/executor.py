@@ -71,6 +71,21 @@ def dummy_context(cfg: ed.EngineConfig) -> ed.GraphContext:
     return DENSE.dummy_context(cfg)
 
 
+def _install_fn(engine: Engine, cfg: ed.EngineConfig):
+    """A pool's install executable: the rows where ``mask`` is set get a
+    fresh state for ``n_tasks`` and the context rows ``rows``; every
+    other row keeps its bits.  The pool's state and context are donated,
+    so the update happens in place."""
+    def install(state, ctx, mask, n_tasks, rows):
+        def put(new, old):
+            return jnp.where(mask.reshape((-1,) + (1,) * (old.ndim - 1)),
+                             new, old)
+        return (jax.tree.map(put, engine.fresh_lane_rows(cfg, n_tasks),
+                             state),
+                jax.tree.map(put, rows, ctx))
+    return jax.jit(install, donate_argnums=(0, 1))
+
+
 class LanePool:
     """Device-side half of a bucket's lane pool: the batched state/context
     pytrees (whatever types ``engine`` mints) plus their static shape.
@@ -127,30 +142,83 @@ class Executor(abc.ABC):
             pool.ctx = jax.device_put(pool.ctx, sh)
         return pool
 
-    def install(self, pool: LanePool, idx: list[int],
-                states: list[ed.DenseState],
-                ctxs: list[ed.GraphContext]) -> None:
-        """Place fresh single-lane (state, ctx) pairs into rows ``idx``
-        (one batched scatter, re-pinned to the backend's sharding; the
-        host span ``mbe.install``)."""
+    def install(self, pool: LanePool, idx: list[int], ctxs: list,
+                n_tasks: list[int], cache: ExecutableCache) -> bool:
+        """Place fresh lanes into rows ``idx``: row ``idx[j]`` gets the
+        host context ``ctxs[j]`` (``Engine.host_context``) and a fresh
+        state owning root tasks ``[0, n_tasks[j])`` (the host span
+        ``mbe.install``).
+
+        An unsharded pool takes its install executable, one per
+        ``(engine, cfg, B)`` in ``cache``: the context rows, padded to
+        ``B``, go to the device in one transfer, the fresh states are
+        built there (``Engine.fresh_lane_rows``), and the pool's buffers
+        are donated, so one dispatch updates the pool in place for any
+        number of lanes.  A sharded pool keeps the shard-by-shard row
+        surgery of ``replace_lanes(sharding=...)``: a partitioned update
+        on a four-chip mesh wrote into other lanes' rows (DESIGN.md §6).
+        Returns True when the install executable placed the lanes, False
+        when row surgery did."""
         with jax.profiler.TraceAnnotation("mbe.install"):
-            pool.state, pool.ctx = ed.replace_lanes(
-                pool.state, pool.ctx, idx,
-                jax.tree.map(lambda *xs: jnp.stack(xs), *states),
-                jax.tree.map(lambda *xs: jnp.stack(xs), *ctxs),
-                sharding=self._pool_sharding())
+            if self._pool_sharding() is not None:
+                self._set_rows(pool, idx, ctxs,
+                               [pool.engine.fresh_lane_state(pool.cfg, n)
+                                for n in n_tasks])
+                return False
+            B = pool.B
+            mask = np.zeros(B, bool)
+            mask[idx] = True
+            nt = np.zeros(B, np.int32)
+            nt[idx] = n_tasks
+
+            def pad(*xs):
+                out = np.zeros((B, *xs[0].shape), xs[0].dtype)
+                out[idx] = np.stack(xs)
+                return out
+
+            entry = cache.get_entry(
+                ("install", self.name, pool.engine.name, pool.cfg, B),
+                lambda: _install_fn(pool.engine, pool.cfg))
+            pool.state, pool.ctx = entry(
+                pool.state, pool.ctx,
+                *jax.device_put((mask, nt, jax.tree.map(pad, *ctxs))))
+            return True
+
+    def restore(self, pool: LanePool, idx: list[int], ctxs: list,
+                states: list) -> None:
+        """Place whole lane states into rows ``idx``: lanes resumed from a
+        host checkpoint (failover, quarantine exoneration) carry a state
+        part-way through its enumeration, not a fresh one, so they take
+        the row surgery of ``replace_lanes``, re-pinned to the backend's
+        sharding (the host span ``mbe.install``)."""
+        with jax.profiler.TraceAnnotation("mbe.install"):
+            self._set_rows(pool, idx, ctxs, states)
+
+    def _set_rows(self, pool: LanePool, idx: list[int], ctxs: list,
+                  states: list) -> None:
+        def stack(*xs):
+            return np.stack(xs)
+        pool.state, pool.ctx = ed.replace_lanes(
+            pool.state, pool.ctx, idx, jax.tree.map(stack, *states),
+            jax.tree.map(stack, *ctxs), sharding=self._pool_sharding())
 
     def migrate(self, old: LanePool, new: LanePool,
                 live_idx: list[int]) -> None:
         """Move live rows of ``old`` into rows [0, len(live_idx)) of
         ``new`` — the pool-widening path: in-flight DFS state resumes
-        unchanged in the wider pool."""
+        unchanged in the wider pool.  The wider pool is assembled on the
+        host and placed with one transfer: a device gather and scatter
+        would take shapes from the number of live lanes, and so compile
+        anew for each count a stream happens to reach."""
         ii = np.asarray(live_idx)
-        new.state, new.ctx = ed.replace_lanes(
-            new.state, new.ctx, np.arange(len(live_idx)),
-            jax.tree.map(lambda x: x[ii], old.state),
-            jax.tree.map(lambda x: x[ii], old.ctx),
-            sharding=self._pool_sharding())
+
+        def put(o, n):
+            out = np.array(n)
+            out[: len(ii)] = np.asarray(o)[ii]
+            return out
+        new.state, new.ctx = jax.device_put(
+            (jax.tree.map(put, old.state, new.state),
+             jax.tree.map(put, old.ctx, new.ctx)), self._pool_sharding())
 
     def evict(self, pool: LanePool, i: int) -> None:
         """Dummy-out lane ``i`` (step-cap eviction, cancellation, deadline

@@ -113,9 +113,9 @@ class FaultInjector(Executor):
 
     The wrapped executor is untouched: every interface method delegates,
     with injection layered on ``run_round`` (launch faults, device-lost,
-    poison), ``done_mask`` (read corruption), ``install`` (poison
-    fingerprinting) and ``big_lane`` (the returned lane is proxied so the
-    big route shares the launch-fault schedule).
+    poison), ``done_mask`` (read corruption), ``install``/``restore``
+    (poison fingerprinting) and ``big_lane`` (the returned lane is
+    proxied so the big route shares the launch-fault schedule).
 
     ``n_injected`` counts every injected fault and ``log`` records them
     as ``(site, ordinal, kind)`` dicts — the reproducibility surface the
@@ -210,7 +210,10 @@ class FaultInjector(Executor):
         self._marks[id(pool)] = set()
         return pool
 
-    def install(self, pool, idx, states, ctxs):
+    def _mark(self, pool, idx, ctxs) -> None:
+        """Fingerprint each installed context.  A host context's bytes are
+        those of the device context it becomes, so poison marked here
+        follows the request onto the big-graph lane too."""
         marks = self._marks.setdefault(id(pool), set())
         for i, ctx in zip(idx, ctxs):
             self._installs += 1
@@ -222,7 +225,14 @@ class FaultInjector(Executor):
                 marks.add(i)
             else:
                 marks.discard(i)
-        return self.inner.install(pool, idx, states, ctxs)
+
+    def install(self, pool, idx, ctxs, n_tasks, cache):
+        self._mark(pool, idx, ctxs)
+        return self.inner.install(pool, idx, ctxs, n_tasks, cache)
+
+    def restore(self, pool, idx, ctxs, states):
+        self._mark(pool, idx, ctxs)
+        return self.inner.restore(pool, idx, ctxs, states)
 
     def migrate(self, old, new, live_idx):
         old_marks = self._marks.get(id(old), set())

@@ -17,7 +17,8 @@ round,
 
 1. lanes whose graph finished are **demuxed** into results immediately,
 2. freed lanes are **refilled in place** from the bucket's pending queue
-   (row surgery — no reshape, no recompile),
+   (one call of the pool's install executable — no reshape, no
+   recompile),
 3. the next round runs with the same executable.
 
 Under ``vmap`` a finished lane otherwise idles until the slowest lane in
@@ -172,7 +173,8 @@ STATS_SCHEMA: dict[str, type | tuple] = dict(
     failed=int, step_capped=int, retries=int, faults_injected=int,
     checkpoints=int, quarantined=int, failovers=int,
     hits=int, misses=int, entries=int, evictions=int,
-    poll_s=float, refill_s=float, demux_s=float, exec_s=float)
+    poll_s=float, refill_s=float, demux_s=float, exec_s=float,
+    installs=int, install_fallbacks=int)
 
 # Monotonic counters (reset by ``MBEServer.reset_stats``); everything
 # else in STATS_SCHEMA is a gauge or a configuration echo.
@@ -184,7 +186,8 @@ MONOTONIC_STATS = frozenset((
     "failed", "step_capped", "retries", "faults_injected",
     "checkpoints", "quarantined", "failovers",
     "hits", "misses", "evictions",
-    "poll_s", "refill_s", "demux_s", "exec_s"))
+    "poll_s", "refill_s", "demux_s", "exec_s",
+    "installs", "install_fallbacks"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -267,17 +270,19 @@ class _LanePool:
         return sum(r is not None for r in self.reqs)
 
     def refill(self, queue: "_PendingQueue", server: "MBEServer") -> int:
-        """Place queued requests into free lanes (one batched row scatter,
-        not one full-pool copy per lane).  The queue pops highest-priority
-        first, so a later high-priority admit overtakes the FIFO backlog
-        at placement time."""
-        idx, states, ctxs = [], [], []
+        """Place queued requests into free lanes: their contexts are built
+        on the host, and the fresh lanes go in with one call of the pool's
+        install executable (``Executor.install``); lanes resumed from a
+        checkpoint go in by row surgery (``Executor.restore``).  The queue
+        pops highest-priority first, so a later high-priority admit
+        overtakes the FIFO backlog at placement time."""
+        idx, ctxs, n_tasks = [], [], []             # fresh lanes
+        r_idx, r_ctxs, r_states = [], [], []        # resumed lanes
         for i in range(self.B):
             if self.reqs[i] is not None or not queue:
                 continue
             r = queue.popleft()
-            idx.append(i)
-            ctxs.append(server.engine.make_context(r.graph, self.cfg))
+            ctx = server.engine.host_context(r.graph, self.cfg)
             snap = server._resume.pop(r.rid, None)
             if snap is not None:
                 # failover / quarantine-exoneration resume: the lane
@@ -285,20 +290,26 @@ class _LanePool:
                 # from scratch (engines are deterministic, so replaying
                 # the <=K rounds since the snapshot is byte-identical);
                 # the latency attribution picks up where it left off
-                states.append(snap.state)
+                r_idx.append(i)
+                r_ctxs.append(ctx)
+                r_states.append(snap.state)
                 self._queue_s[i] = snap.queue_s
                 self._service_s[i] = snap.service_s
                 self._compile_s[i] = snap.compile_s
             else:
-                states.append(server.engine.fresh_lane_state(
-                    self.cfg, r.graph.n_u))
+                idx.append(i)
+                ctxs.append(ctx)
+                n_tasks.append(r.graph.n_u)
                 self._queue_s[i] = time.perf_counter() - r.t_admit
                 self._service_s[i] = 0.0
                 self._compile_s[i] = 0.0
             self.reqs[i] = r
         if idx:
-            server.executor.install(self.pool, idx, states, ctxs)
-        return len(idx)
+            server._install(self.pool, idx, ctxs, n_tasks)
+        if r_idx:
+            server.executor.restore(self.pool, r_idx, r_ctxs, r_states)
+            server._n_install_fallbacks += len(r_idx)
+        return len(idx) + len(r_idx)
 
     def run_round(self, server: "MBEServer") -> bool:
         """One bounded executor round over all lanes; occupancy
@@ -490,6 +501,8 @@ class MBEServer:
         self._poll_s = 0.0
         self._refill_s = 0.0
         self._demux_s = 0.0
+        self._n_installs = 0            # refills by the install executable
+        self._n_install_fallbacks = 0   # lanes placed by row surgery
         self._n_launches = 0
         self._rebalanced_steps = 0
         self._n_cancelled = 0
@@ -1049,6 +1062,15 @@ class MBEServer:
             self._quarantine(lanepool, e)
             return None
 
+    def _install(self, pool, idx: list[int], ctxs: list,
+                 n_tasks: list[int]) -> None:
+        """Place fresh lanes through the executor, counting calls that
+        took the install executable and lanes that took row surgery."""
+        if self.executor.install(pool, idx, ctxs, n_tasks, self.cache):
+            self._n_installs += 1
+        else:
+            self._n_install_fallbacks += len(idx)
+
     def _probe_fails(self, lanepool: _LanePool, reqs: list[Request],
                      budget) -> bool:
         """Quarantine probe: install ``reqs`` fresh into the (emptied)
@@ -1057,11 +1079,10 @@ class MBEServer:
         group.  Probe work is throwaway (the survivors restart from their
         checkpoints/fresh on requeue), so it enters no occupancy ledger."""
         idx = list(range(len(reqs)))
-        states = [self.engine.fresh_lane_state(lanepool.cfg, r.graph.n_u)
-                  for r in reqs]
-        ctxs = [self.engine.make_context(r.graph, lanepool.cfg)
-                for r in reqs]
-        self.executor.install(lanepool.pool, idx, states, ctxs)
+        self._install(lanepool.pool, idx,
+                      [self.engine.host_context(r.graph, lanepool.cfg)
+                       for r in reqs],
+                      [r.graph.n_u for r in reqs])
         try:
             self._with_retry(
                 "quarantine-probe",
@@ -1413,6 +1434,11 @@ class MBEServer:
                     # the host blocks on a round, pools and big lane)
                     poll_s=self._poll_s, refill_s=self._refill_s,
                     demux_s=self._demux_s, exec_s=self._exec_s,
+                    # lane placement: refills that took the pool's install
+                    # executable, and lanes placed by row surgery instead
+                    # (sharded pools, lanes resumed from a checkpoint)
+                    installs=self._n_installs,
+                    install_fallbacks=self._n_install_fallbacks,
                     **self.cache.stats())
 
     def reset_stats(self) -> None:
@@ -1431,7 +1457,8 @@ class MBEServer:
         ``checkpoints``, ``quarantined``, ``failovers``,
         ``per_tenant``, ``big_busy_per_worker``, ``big_imbalance``,
         the host time counters ``poll_s``, ``refill_s``, ``demux_s``,
-        ``exec_s``, and the cache counters ``hits``/``misses``/
+        ``exec_s``, the placement counters ``installs`` and
+        ``install_fallbacks``, and the cache counters ``hits``/``misses``/
         ``evictions`` (so the miss count stays an honest per-phase
         compile count).
 
@@ -1450,6 +1477,8 @@ class MBEServer:
         self._poll_s = 0.0
         self._refill_s = 0.0
         self._demux_s = 0.0
+        self._n_installs = 0
+        self._n_install_fallbacks = 0
         self._n_launches = 0
         self._rebalanced_steps = 0
         self._n_cancelled = 0

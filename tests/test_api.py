@@ -200,10 +200,13 @@ def test_compact_engine_served_through_buckets_and_cache():
     st = srv.stats()
     assert st["engine"] == "compact"
     assert st["misses"] < len(graphs)          # bucketing amortized
-    for (head, _batch, _budget) in srv.cache._entries:
+    for key in srv.cache._entries:
         # compact entries are engine-qualified so they can never collide
         # with a dense executable for the same bucket
-        assert head[0] == "compact", head
+        if key[0] == "install":
+            assert key[2] == "compact", key
+        else:
+            assert key[0][0] == "compact", key
 
 
 # ---------------------------------------------------------------------------

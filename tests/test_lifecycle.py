@@ -62,7 +62,8 @@ def test_cancel_pending_other_buckets_unaffected():
     assert got[doomed].cancelled
     assert not got[survivor_a].cancelled and not got[survivor_b].cancelled
     assert got[survivor_a].n_max >= 0 and got[survivor_b].n_max >= 0
-    assert srv.cache.misses == 1                 # ONLY the (16,32) pool
+    # ONLY the (16,32) pool compiles: its round and install executables
+    assert srv.cache.misses == 2
 
 
 def test_cancel_in_flight_frees_lane_and_next_request_refills_it():
@@ -84,9 +85,11 @@ def test_cancel_in_flight_frees_lane_and_next_request_refills_it():
     assert got[rid_h].steps > 0                  # partial progress reported
     assert got[rid_l].status == "done"
     assert got[rid_l].n_max == int(ed.enumerate_dense(light).n_max)
-    # one lane pool, one executable: the refill reused the evicted slot
-    batches = {b for (_c, b, _s) in srv.cache._entries}
-    assert batches == {1}
+    # one lane pool, one round and one install executable: the refill
+    # reused the evicted slot
+    batches = {k[-1] if k[0] == "install" else k[1]
+               for k in srv.cache._entries}
+    assert batches == {1} and srv.cache.misses == 2
     assert srv.stats()["lanes"] == 2             # two placements, one lane
 
 
@@ -165,8 +168,9 @@ def test_deadline_pending_expiry_returns_timed_out_without_compiling():
     assert got[rid_n].status == "done"
     assert got[rid_n].n_max == int(
         ed.enumerate_dense(_random_graph(10, 20, 0.2, 5)).n_max)
-    # exactly one executable compiled — for the surviving request's pool
-    assert srv.cache.misses == misses_before + 1
+    # exactly one pool's executables compiled (its round and install) —
+    # for the surviving request's pool
+    assert srv.cache.misses == misses_before + 2
     assert srv.stats()["timed_out"] == 1
 
 

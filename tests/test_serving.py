@@ -112,7 +112,8 @@ def test_non_pow2_max_batch_server_end_to_end():
     results = srv.serve(graphs)
     for g, r in zip(graphs, results):
         assert r.n_max == int(ed.enumerate_dense(g).n_max)
-    for (_cfg, batch, _budget) in srv.cache._entries:
+    for key in srv.cache._entries:
+        batch = key[-1] if key[0] == "install" else key[1]
         assert batch & (batch - 1) == 0 and batch <= 6
 
 
@@ -285,13 +286,18 @@ def test_continuous_mode_identical_to_flush_on_mixed_stream():
         assert (a.n_max, a.cs) == (b.n_max, b.cs), g.name
         assert bicliques_to_key_set(a.bicliques) == \
             bicliques_to_key_set(b.bicliques), g.name
-    # every continuous executable is a round-mode entry: one per
-    # (bucket, batch) pair, with the round budget in the key
+    # every continuous executable is a round-mode entry, one per
+    # (bucket, batch) pair with the round budget in the key, or that
+    # pool's install executable
     st_ = cont.stats()
     assert st_["misses"] == st_["entries"]
     assert st_["pending"] == 0 and st_["in_flight"] == 0
-    for (_cfg, _batch, budget) in cont.cache._entries:
+    rounds = [k for k in cont.cache._entries if k[0] != "install"]
+    installs = [k for k in cont.cache._entries if k[0] == "install"]
+    for (_cfg, _batch, budget) in rounds:
         assert budget == 24
+    assert sorted((k[3].n_u, k[3].n_v, k[4]) for k in installs) == \
+        sorted((c.n_u, c.n_v, b) for (c, b, _s) in rounds)
 
 
 def test_refill_lifts_occupancy_on_skewed_stream():
@@ -355,7 +361,7 @@ def test_pool_grows_for_burst_after_trickle():
     burst = [_random_graph(10, 20, 0.1, s) for s in range(7)]
     rids = [srv.admit(g) for g in burst]
     got = srv.drain()
-    batches = {b for (_c, b, _s) in srv.cache._entries}
+    batches = {k[1] for k in srv.cache._entries if k[0] != "install"}
     assert max(batches) == 8                     # pool widened for the burst
     assert got[rid_h].n_max == int(ed.enumerate_dense(heavy).n_max)
     for g, rid in zip(burst, rids):

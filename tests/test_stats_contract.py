@@ -215,3 +215,74 @@ def test_reset_stats_covers_exactly_the_monotonic_keys():
     assert after["entries"] == before["entries"]
     assert after["engine"] == before["engine"]
     assert after["kernel_impl"] == before["kernel_impl"]
+
+
+#: the lane-placement counters: refills that took a pool's install
+#: executable, and lanes placed by row surgery instead
+PLACEMENT_COUNTERS = {"installs", "install_fallbacks"}
+
+
+def test_placement_counters_are_contract_keys():
+    assert PLACEMENT_COUNTERS <= set(STATS_SCHEMA)
+    assert PLACEMENT_COUNTERS <= MONOTONIC_STATS
+    for key in PLACEMENT_COUNTERS:
+        assert STATS_SCHEMA[key] is int
+
+
+def test_local_pools_place_every_lane_through_the_install_executable():
+    """On a local executor each refill that places lanes is one install
+    call and no lane takes row surgery; warm refills compile nothing."""
+    srv = MBEServer(BucketPolicy(max_batch=4, steps_per_round=8))
+    gs = [random_graph(6 + i % 2, 12, 0.3, 30 + i, canonical=True)
+          for i in range(9)]
+    for g in gs[:4]:
+        srv.admit(g)
+    srv.drain()
+    first = srv.stats()
+    assert first["install_fallbacks"] == 0
+    assert 1 <= first["installs"] <= first["lanes"]
+    srv.reset_stats()
+    for g in gs[4:]:
+        srv.admit(g)
+    srv.drain()
+    s = srv.stats()
+    assert s["install_fallbacks"] == 0
+    assert 1 <= s["installs"] <= s["lanes"] == 5
+    srv.reset_stats()
+    assert srv.stats()["installs"] == 0
+
+
+def test_sharded_pools_place_lanes_by_row_surgery():
+    """A pool whose lane axis is sharded over a mesh keeps the
+    shard-by-shard row surgery: every placed lane is a fallback."""
+    srv = MBEServer(BucketPolicy(max_batch=2),
+                    executor=ShardedExecutor(mbe_serve_mesh(1)))
+    for g in _graphs_for("dense", n=3):
+        srv.admit(g)
+    got = srv.drain()
+    assert all(r.status == "done" for r in got.values())
+    s = srv.stats()
+    assert s["installs"] == 0
+    assert s["install_fallbacks"] == s["lanes"] == 3
+
+
+def test_failover_resume_places_checkpointed_lanes_by_row_surgery():
+    """Lanes resumed from a host checkpoint after a failover carry a whole
+    state, so they take row surgery; fresh lanes still take the install
+    executable."""
+    srv = MBEServer(
+        BucketPolicy(max_batch=2, steps_per_round=4),
+        retry=RetryPolicy(max_attempts=3, backoff_s=1e-5,
+                          checkpoint_interval=1),
+        fault_injector=FaultPlan(seed=1, device_lost_after=3))
+    gs = [random_graph(8 + i, 14, 0.35, 60 + i, canonical=True)
+          for i in range(4)]
+    for g in gs:
+        srv.admit(g)
+    got = srv.drain()
+    assert all(r.status == "done" for r in got.values())
+    s = srv.stats()
+    assert s["failovers"] == 1
+    assert s["install_fallbacks"] >= 1
+    assert s["installs"] >= 1
+    assert s["lanes"] >= len(gs) + s["install_fallbacks"]
