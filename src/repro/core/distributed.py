@@ -141,7 +141,10 @@ def make_round_fn(cfg: ed.EngineConfig, mesh: Mesh,
       round (its slice of the round's work, the Fig.-5 load data), and
     * ``pending``    — unstarted root tasks left in each worker's queue
       AFTER the steal re-deal (what a scheduler needs to decide whether
-      the lane is starving or saturated).
+      the lane is starving or saturated), and
+    * for engines that count kernel work (``Engine.work_rows``),
+      ``work`` — the rows each worker's kernel passes had to read this
+      round.
 
     The serving executors consume the telemetry form; the classic driver
     keeps the bare-state form for backward compatibility.
@@ -166,6 +169,7 @@ def make_round_fn(cfg: ed.EngineConfig, mesh: Mesh,
     def _per_device(ctx: ed.GraphContext, s: ed.DenseState):
         # s leaves have leading dim = workers_per_device
         steps_before = s.steps
+        work_before = engine.work_rows(s)
         s = engine.run_batch(ctx, cfg, s, max_steps=dist.steps_per_round,
                              ctx_batched=ctx_batched,
                              unroll=dist.steps_per_call)
@@ -189,6 +193,9 @@ def make_round_fn(cfg: ed.EngineConfig, mesh: Mesh,
         if not with_telemetry:
             return s
         telem = dict(busy_steps=busy, pending=s.n_tasks - s.tpos)
+        if work_before is not None:
+            telem["work"] = {k: v - work_before[k]
+                             for k, v in engine.work_rows(s).items()}
         return s, telem
 
     spec_leaf = P(axis_names)

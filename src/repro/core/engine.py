@@ -51,9 +51,9 @@ the engine's own hooks.
 The MBE engines share ``EngineConfig`` and the collect-buffer scalar
 tail (``n_max``/``cs``/``out_n``/``out_l``/``out_r``); both enumerate
 the same maximal bicliques with the same order-independent fingerprint
-(``cs``); ``steps``/``nodes`` may differ (the compact engine walks a
-padded P region the dense engine masks out), so "byte-identical" claims
-compare ``(n_max, cs)`` and decoded biclique sets, never step counts.
+(``cs``); ``steps``/``nodes`` may differ between the engines, so
+"byte-identical" claims compare ``(n_max, cs)`` and decoded biclique
+sets, never step counts.
 
 Registry: ``register_engine`` installs an engine under its ``name``
 (duplicate names raise — pass ``override=True`` to swap in a tuned
@@ -213,6 +213,16 @@ class Engine(abc.ABC):
         when this is nonzero, so engines without a pool path keep their
         legacy keys byte-for-byte."""
         return 0
+
+    def work_rows(self, s) -> dict | None:
+        """Adjacency rows each kernel pass of this engine has had to
+        read so far, per worker: ``{pass: int32 array}`` over the state's
+        leading axes, counted on the device whatever kernel path runs.
+        The serving layer reads the round's deltas with its ``steps``
+        telemetry and reports them as words in ``stats()``
+        (``<pass>_words``).  None, the default, for engines that count
+        no such work: their state and executables carry nothing extra."""
+        return None
 
     # -- collect / decode hooks ----------------------------------------
     def done(self, s) -> jax.Array:
@@ -386,6 +396,9 @@ class CompactEngine(Engine):
 
     def run(self, ctx, cfg, s, max_steps=None, unroll=1):
         return ec.run(ctx, cfg, s, max_steps=max_steps, unroll=unroll)
+
+    def work_rows(self, s):
+        return dict(gathered_select=s.sel_rows, gathered_check=s.chk_rows)
 
 
 # ---------------------------------------------------------------------------

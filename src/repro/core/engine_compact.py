@@ -94,6 +94,8 @@ class CompactState(NamedTuple):
     out_n: jax.Array
     out_l: jax.Array
     out_r: jax.Array
+    sel_rows: jax.Array   # () i32 rows the select passes had to read
+    chk_rows: jax.Array   # () i32 rows the check passes had to read
 
 
 def host_context(g: BipartiteGraph, cfg: EngineConfig) -> CompactContext:
@@ -152,7 +154,7 @@ def init_state(cfg: EngineConfig, tasks: np.ndarray) -> CompactState:
         tasks=jnp.asarray(t), n_tasks=jnp.int32(len(tasks)), tpos=z,
         steps=z, nodes=z, n_max=z, max_fail=z, cs=jnp.uint32(0),
         out_n=z, out_l=jnp.zeros((C, WV), jnp.uint32),
-        out_r=jnp.zeros((C, WU), jnp.uint32))
+        out_r=jnp.zeros((C, WU), jnp.uint32), sel_rows=z, chk_rows=z)
 
 
 # ---------------------------------------------------------------------------
@@ -172,9 +174,16 @@ def _branch_init_task(g: CompactContext, cfg, s: CompactState
                       ) -> CompactState:
     idx = s.tasks[jnp.minimum(s.tpos, s.tasks.shape[0] - 1)]
     x = g.order[jnp.clip(idx, 0, cfg.n_u - 1)]
+    # the root's P region holds the real vertices after x in the order:
+    # p_static keeps them reversed in positions [0, m), so the region is
+    # [0, m - 1 - idx).  m is the graph's own vertex count (its order
+    # entries), not the bucket's: a wider region would take x, the roots
+    # already in Q and the padding rows into P as well, and the search
+    # would only re-explore and reject them.
+    m = jnp.sum(g.order >= 0, dtype=jnp.int32)
     return s._replace(
         P=g.p_static, lookup=g.lk_static, Q=g.q_static,
-        p_ptr=s.p_ptr.at[0].set(jnp.int32(cfg.m_real) - 1 - idx),
+        p_ptr=s.p_ptr.at[0].set(m - 1 - idx),
         q_ptr=s.q_ptr.at[0].set(idx),
         lmask=s.lmask.at[0].set(g.l_root),
         rmask=s.rmask.at[0].set(jnp.zeros((cfg.wu,), jnp.uint32)),
@@ -237,12 +246,11 @@ def _branch_candidate(g: CompactContext, cfg: EngineConfig,
         # two scalars instead of two (2N,) comparison vectors built and
         # shipped per step; the kernel rebuilds the position predicates
         # from its iota against the static Q/P split.
-        viol_f, full2, part2, _, _ = fused_check_gathered_prefix2(
+        viol_f, _, part2, _, _ = fused_check_gathered_prefix2(
             g.adj, jnp.concatenate([s.Q, P1]), Lp, nLp,
             s.q_ptr[lvl], p_work, impl="pallas")
         viol = viol_f & nonempty
-        fullb = full2[cfg.n_u:]                   # per-position flags
-        partb = part2[cfg.n_u:]
+        partb = part2[cfg.n_u:]                   # per-position flags
     else:
         rows_q = g.adj[s.Q]
         c_q = intersect_count(rows_q, Lp, impl=cfg.impl)
@@ -250,10 +258,13 @@ def _branch_candidate(g: CompactContext, cfg: EngineConfig,
         rows_p1 = g.adj[P1]
         c_p = intersect_count(rows_p1, Lp, impl=cfg.impl)
         act = pos < p_work
-        fullb = act & (c_p == nLp)                # per-position flags
-        partb = act & (c_p > 0) & (c_p < nLp)
+        partb = act & (c_p > 0) & (c_p < nLp)    # per-position flags
     is_max = nonempty & ~viol
-    fullv = jnp.zeros(cfg.n_u, bool).at[P1].set(fullb)   # per-vertex
+    # per-vertex full flags through the lookup table (v is in the live
+    # region iff lookup1[v] < p_work): one pass over adj in vertex order
+    # instead of scattering per-position flags through P1
+    fullv = (lookup1 < p_work) \
+        & (bitset.count(g.adj & Lp[None, :]) == nLp)
     Rp = s.rmask[lvl] | bitset.singleton(x, cfg.wu) \
         | bitset.from_bool(fullv)
     has_child = is_max & jnp.any(partb)
@@ -271,8 +282,7 @@ def _branch_candidate(g: CompactContext, cfg: EngineConfig,
 
     # -- descend: stable-compact survivors to the region front -----------
     key = jnp.where(pos < p_work, jnp.where(partb, 0, 1), 2)
-    perm = jnp.argsort(key, stable=True)
-    P_child = P1[perm]
+    _, P_child = jax.lax.sort((key, P1), num_keys=1, is_stable=True)
     lk_child = jnp.zeros_like(s.lookup).at[P_child].set(pos)
     n_part = jnp.sum(partb).astype(jnp.int32)
 
@@ -293,6 +303,14 @@ def _branch_candidate(g: CompactContext, cfg: EngineConfig,
         jnp.where(has_child, s.Q[0], x))
     q_ptr = q_ptr.at[lvl].set(jnp.where(has_child, q_ptr[lvl], qp + 1))
 
+    # -- work: the rows each pass had to read, whatever the kernel path
+    # streams.  The level pointers are the active row counts: p rows of
+    # adj[P] to select x (none when the root forces it), q_ptr + p_work
+    # rows of adj[Q ++ P'] to check it; each pass reads the mask row too.
+    sel = (jnp.where(forced, 0, p + 1) if cfg.order_mode == "deg"
+           else jnp.int32(0))
+    chk = s.q_ptr[lvl] + p_work + 1
+
     return s._replace(
         P=P2, lookup=lookup2, p_ptr=p_ptr, Q=Q, q_ptr=q_ptr,
         lmask=lmask, rmask=rmask, xstack=xstack,
@@ -300,7 +318,8 @@ def _branch_candidate(g: CompactContext, cfg: EngineConfig,
         forced_x=jnp.int32(-1),
         nodes=s.nodes + 1, n_max=n_max,
         max_fail=s.max_fail + (viol & nonempty).astype(jnp.int32),
-        cs=cs, out_n=out_n, out_l=out_l, out_r=out_r)
+        cs=cs, out_n=out_n, out_l=out_l, out_r=out_r,
+        sel_rows=s.sel_rows + sel, chk_rows=s.chk_rows + chk)
 
 
 # ---------------------------------------------------------------------------

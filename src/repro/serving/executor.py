@@ -112,6 +112,33 @@ class RoundTelemetry:
     adv: np.ndarray               # per-lane/worker engine steps advanced
     pending: np.ndarray | None = None   # per-worker unstarted root tasks
     #                                     (work-stealing lanes only)
+    work: dict | None = None      # per-lane/worker rows each kernel pass
+    #                               had to read this round
+    #                               (``Engine.work_rows`` deltas; None for
+    #                               engines that count none)
+
+
+def _progress(engine: Engine, state):
+    """What a round's telemetry reads of a pool's state: the lanes'
+    ``steps`` and, for engines that count it, their ``work_rows``, in
+    one device-to-host read."""
+    work = engine.work_rows(state)
+    if work is None:
+        return np.asarray(state.steps), None
+    return jax.device_get((state.steps, work))
+
+
+def _widen(work: dict | None) -> dict | None:
+    """A round's per-lane int32 row counts as int64."""
+    return None if work is None else {
+        k: np.asarray(v).astype(np.int64) for k, v in work.items()}
+
+
+def _delta(after: dict | None, before: dict | None) -> dict | None:
+    """Per-lane growth of the int32 work counters over a round, taken
+    modulo 2**32 (a round adds far less than 2**31)."""
+    return None if after is None else _widen(
+        {k: v - before[k] for k, v in after.items()})
 
 
 class Executor(abc.ABC):
@@ -301,11 +328,13 @@ class LocalExecutor(Executor):
                   budget: int | None, unroll: int = 1) -> RoundTelemetry:
         entry = cache.get_round(pool.cfg, pool.B, budget,
                                 engine=pool.engine, unroll=unroll)
-        before = np.asarray(pool.state.steps)
+        steps0, work0 = _progress(pool.engine, pool.state)
         out, wall, compile_s = entry.timed_call(pool.ctx, pool.state)
         pool.state = out
+        steps1, work1 = _progress(pool.engine, out)
         return RoundTelemetry(wall_s=wall, compile_s=compile_s,
-                              adv=np.asarray(out.steps) - before)
+                              adv=steps1 - steps0,
+                              work=_delta(work1, work0))
 
     def placement(self, n_lanes: int) -> str:
         return f"1 device x {n_lanes} vmap lanes"
@@ -387,10 +416,12 @@ class ShardedExecutor(Executor):
         (out, telem), wall, compile_s = entry.timed_call(pool.ctx,
                                                          pool.state)
         pool.state = out
+        telem = jax.device_get(telem)
         return RoundTelemetry(
             wall_s=wall, compile_s=compile_s,
             adv=np.asarray(telem["busy_steps"]),
-            pending=np.asarray(telem["pending"]))
+            pending=np.asarray(telem["pending"]),
+            work=_widen(telem.get("work")))
 
     def launches_per_segment(self, pool: LanePool) -> int:
         wpd = pool.B // self.n_devices
@@ -477,12 +508,14 @@ class BigGraphLane:
         (out, telem), wall, compile_s = self._entry.timed_call(self.ctx,
                                                                self.state)
         self.state = out
+        telem = jax.device_get(telem)
         adv = np.asarray(telem["busy_steps"], np.int64)
         self.busy_per_worker += adv
         self.rounds += 1
         return RoundTelemetry(
             wall_s=wall, compile_s=compile_s, adv=adv,
-            pending=np.asarray(telem["pending"]))
+            pending=np.asarray(telem["pending"]),
+            work=_widen(telem.get("work")))
 
     @property
     def done(self) -> bool:

@@ -114,7 +114,8 @@ from repro.kernels.dispatch import resolve_impl
 from repro.serving.buckets import (BucketPolicy, BucketSpec, plan_bucket,
                                    plan_route)
 from repro.serving.cache import ExecutableCache
-from repro.serving.executor import BigGraphLane, Executor, LocalExecutor
+from repro.serving.executor import (BigGraphLane, Executor, LocalExecutor,
+                                    RoundTelemetry)
 from repro.serving.faults import DeviceLostError, FaultInjector, FaultPlan
 from repro.serving.recovery import (CheckpointStore, RetryPolicy,
                                     verified_read)
@@ -174,7 +175,8 @@ STATS_SCHEMA: dict[str, type | tuple] = dict(
     checkpoints=int, quarantined=int, failovers=int,
     hits=int, misses=int, entries=int, evictions=int,
     poll_s=float, refill_s=float, demux_s=float, exec_s=float,
-    installs=int, install_fallbacks=int)
+    installs=int, install_fallbacks=int,
+    gathered_select_words=int, gathered_check_words=int)
 
 # Monotonic counters (reset by ``MBEServer.reset_stats``); everything
 # else in STATS_SCHEMA is a gauge or a configuration echo.
@@ -187,7 +189,12 @@ MONOTONIC_STATS = frozenset((
     "checkpoints", "quarantined", "failovers",
     "hits", "misses", "evictions",
     "poll_s", "refill_s", "demux_s", "exec_s",
-    "installs", "install_fallbacks"))
+    "installs", "install_fallbacks",
+    "gathered_select_words", "gathered_check_words"))
+
+# The kernel passes whose work an engine may count (``Engine.work_rows``),
+# reported in stats() as ``<pass>_words``.
+WORK_PASSES = ("gathered_select", "gathered_check")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -328,6 +335,7 @@ class _LanePool:
         server._busy_steps += busy
         server._total_lane_steps += self.B * crit
         server._exec_s += exec_s
+        server._add_work(tel, self.cfg)
         # launch accounting: the round's critical path ran ceil(crit/spc)
         # compiled segments, each costing launches_per_segment kernel
         # dispatches (1 per pool on the multi-lane path, B on vmap)
@@ -503,6 +511,7 @@ class MBEServer:
         self._demux_s = 0.0
         self._n_installs = 0            # refills by the install executable
         self._n_install_fallbacks = 0   # lanes placed by row surgery
+        self._work_words = dict.fromkeys(WORK_PASSES, 0)
         self._n_launches = 0
         self._rebalanced_steps = 0
         self._n_cancelled = 0
@@ -800,6 +809,7 @@ class MBEServer:
         self._busy_steps += busy
         self._total_lane_steps += slot.lane.n_workers * crit
         self._exec_s += exec_s
+        self._add_work(tel, slot.lane.cfg)
         # launch accounting mirrors the pool rounds: inside shard_map
         # each device advances wpd workers, in ONE pool launch per
         # segment when the multi-lane kernel is active, else wpd
@@ -1061,6 +1071,13 @@ class MBEServer:
         except self.retry.retry_on as e:
             self._quarantine(lanepool, e)
             return None
+
+    def _add_work(self, tel: RoundTelemetry, cfg) -> None:
+        """Add a round's kernel work (rows per lane, read with its
+        ``steps``) to the ``<pass>_words`` counters: one adjacency row is
+        ``cfg.wv`` words."""
+        for k, rows in (tel.work or {}).items():
+            self._work_words[k] += int(rows.sum()) * cfg.wv
 
     def _install(self, pool, idx: list[int], ctxs: list,
                  n_tasks: list[int]) -> None:
@@ -1439,6 +1456,9 @@ class MBEServer:
                     # (sharded pools, lanes resumed from a checkpoint)
                     installs=self._n_installs,
                     install_fallbacks=self._n_install_fallbacks,
+                    # adjacency words each kernel pass had to read
+                    # (Engine.work_rows; 0 for engines that count none)
+                    **{f"{k}_words": v for k, v in self._work_words.items()},
                     **self.cache.stats())
 
     def reset_stats(self) -> None:
@@ -1458,7 +1478,9 @@ class MBEServer:
         ``per_tenant``, ``big_busy_per_worker``, ``big_imbalance``,
         the host time counters ``poll_s``, ``refill_s``, ``demux_s``,
         ``exec_s``, the placement counters ``installs`` and
-        ``install_fallbacks``, and the cache counters ``hits``/``misses``/
+        ``install_fallbacks``, the kernel work counters
+        ``gathered_select_words`` and ``gathered_check_words``, and the
+        cache counters ``hits``/``misses``/
         ``evictions`` (so the miss count stays an honest per-phase
         compile count).
 
@@ -1479,6 +1501,7 @@ class MBEServer:
         self._demux_s = 0.0
         self._n_installs = 0
         self._n_install_fallbacks = 0
+        self._work_words = dict.fromkeys(WORK_PASSES, 0)
         self._n_launches = 0
         self._rebalanced_steps = 0
         self._n_cancelled = 0

@@ -286,3 +286,29 @@ def test_failover_resume_places_checkpointed_lanes_by_row_surgery():
     assert s["install_fallbacks"] >= 1
     assert s["installs"] >= 1
     assert s["lanes"] >= len(gs) + s["install_fallbacks"]
+
+
+#: the kernel work counters (``Engine.work_rows``): adjacency words the
+#: compact engine's gathered select and check passes had to read
+WORK_COUNTERS = {"gathered_select_words", "gathered_check_words"}
+
+
+def test_work_counters_are_contract_keys():
+    """In the schema as ints, monotonic, moved by a compact stream and
+    zeroed by ``reset_stats``."""
+    assert WORK_COUNTERS <= set(STATS_SCHEMA)
+    assert WORK_COUNTERS <= MONOTONIC_STATS
+    for key in WORK_COUNTERS:
+        assert STATS_SCHEMA[key] is int
+    srv = MBEServer(BucketPolicy(max_batch=2, steps_per_round=8),
+                    engine="compact")
+    for g in _graphs_for("compact", n=2):
+        srv.admit(g)
+    srv.drain()
+    stats = srv.stats()
+    _assert_schema(stats)
+    for key in WORK_COUNTERS:
+        assert stats[key] > 0
+    srv.reset_stats()
+    for key in WORK_COUNTERS:
+        assert srv.stats()[key] == 0

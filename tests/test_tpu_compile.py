@@ -145,3 +145,31 @@ def test_resident_pool_segment_compiles(one_chip, n_u, n_v, ctx_batched):
         ctx_batched=ctx_batched, interpret=False),
         _tree_spec(one_chip, ctx, (lanes,) if ctx_batched else ()),
         _tree_spec(one_chip, state, (lanes,)))
+
+
+def test_compact_round_compiles_at_the_ucforum_bucket(one_chip, monkeypatch):
+    """The compact engine's served round executable, as the scheduler
+    builds it (``ExecutableCache.get_round``) for KONECT opsahl-ucforum's
+    pow2 bucket: 1024 x 1024, 8 lanes, 512 steps a round, 8 steps per
+    call, the gathered Pallas kernels with ``interpret=False``."""
+    from repro.core.engine import COMPACT
+    from repro.core.graph import BipartiteGraph
+    from repro.kernels.fused_check import ops as check_ops
+    from repro.kernels.fused_select import ops as select_ops
+    from repro.serving.buckets import BucketPolicy, plan_bucket
+    for ops in (select_ops, check_ops):
+        monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    jax.clear_caches()              # no trace kept from interpret mode
+    g = BipartiteGraph.from_edges(522, 899, [(0, 0)])
+    bucket = plan_bucket(g, BucketPolicy(mode="pow2", max_batch=8))
+    assert (bucket.n_u, bucket.n_v) == (1024, 1024)
+    cfg = COMPACT.config(bucket.n_u, bucket.n_v, bucket.depth,
+                         kernel_impl="pallas")
+    lanes = 8
+    ctx = jax.eval_shape(lambda: COMPACT.dummy_context(cfg))
+    state = jax.eval_shape(lambda: COMPACT.fresh_lane_state(cfg, 0))
+    _compile(lambda c, s: COMPACT.run_batch(
+        c, cfg, s, max_steps=512, ctx_batched=True, unroll=8),
+        _tree_spec(one_chip, ctx, (lanes,)),
+        _tree_spec(one_chip, state, (lanes,)))
+    jax.clear_caches()
