@@ -214,6 +214,13 @@ class Engine(abc.ABC):
         legacy keys byte-for-byte."""
         return 0
 
+    def stepwise_lanes(self, cfg: EngineConfig, batch: int) -> bool:
+        """Whether ``run_batch`` advances ``batch`` lanes on the dense
+        engine's lane-masked per-step loop; the serving layer counts
+        the steps such rounds advance (``stats()['stepwise_steps']``).
+        False for engines that have no such loop."""
+        return False
+
     def work_rows(self, s) -> dict | None:
         """Adjacency rows each kernel pass of this engine has had to
         read so far, per worker: ``{pass: int32 array}`` over the state's
@@ -366,6 +373,9 @@ class DenseEngine(Engine):
 
     def pool_lanes(self, cfg, batch):
         return ed.pool_lanes(cfg, batch)
+
+    def stepwise_lanes(self, cfg, batch):
+        return ed.stepwise_lanes(cfg, batch)
 
 
 class CompactEngine(Engine):

@@ -302,12 +302,22 @@ def _delta_zeros(cfg: EngineConfig, s: DenseState) -> Delta:
         ow_r=jnp.zeros((cfg.wu,), jnp.uint32), ow_en=f)
 
 
+def _row(stack: jax.Array, i: jax.Array) -> jax.Array:
+    """``stack[i]`` for a traced level ``i`` (negative ``i`` counts from
+    the end, out of range clamps), read as a one-row gather.  Under
+    ``vmap`` plain indexing becomes a gather for which a TPU keeps the
+    stack in a second layout, so every row write then copied the whole
+    stack across (DESIGN.md §11)."""
+    i = jnp.where(i < 0, i + stack.shape[0], i)
+    return jnp.take(stack, i, axis=0, mode="clip")
+
+
 def _branch_backtrack(g: GraphContext, cfg: EngineConfig,
                       s: DenseState) -> Delta:
     nl = s.lvl - 1
     safe = jnp.maximum(nl, 0)
-    x = s.xstack[safe]
-    q_new = bitset.add(s.qmask[safe], jnp.maximum(x, 0))
+    x = _row(s.xstack, safe)
+    q_new = bitset.add(_row(s.qmask, safe), jnp.maximum(x, 0))
     return _delta_zeros(cfg, s)._replace(
         q_row=q_new, q_idx=safe, q_en=nl >= 0, lvl=nl)
 
@@ -332,8 +342,9 @@ def _branch_init_task(g: GraphContext, cfg: EngineConfig,
 def _branch_candidate(g: GraphContext, cfg: EngineConfig,
                       s: DenseState) -> Delta:
     lvl = s.lvl
-    L = s.lmask[lvl]
-    pm = s.pmask[lvl]
+    L = _row(s.lmask, lvl)
+    pm = _row(s.pmask, lvl)
+    q_row = _row(s.qmask, lvl)
     forced = s.forced_x >= 0
 
     # -- Step 1: candidate selection ------------------------------------
@@ -342,7 +353,7 @@ def _branch_candidate(g: GraphContext, cfg: EngineConfig,
         # selection is a cheap packed-masked argmin, zero adjacency
         # passes on EITHER kernel path (the cache is refilled by the
         # check pass)
-        x_sel = bitset.masked_argmin(s.cstack[lvl], pm)
+        x_sel = bitset.masked_argmin(_row(s.cstack, lvl), pm)
     elif cfg.order_mode == "deg_nocache":
         if cfg.fused:
             # one VMEM-resident pass: counts + masked argmin, nothing
@@ -374,7 +385,7 @@ def _branch_candidate(g: GraphContext, cfg: EngineConfig,
     if cfg.fused:
         with_counts = cfg.order_mode == "deg"
         viol_f, fullw, partw, nzw, c2 = fused_check_packed(
-            g.adj, Lp, nLp, s.qmask[lvl], pm_after,
+            g.adj, Lp, nLp, q_row, pm_after,
             impl="pallas", with_counts=with_counts)
         viol = viol_f & nonempty
         c_row = c2 if with_counts else jnp.zeros((cfg.n_u,), jnp.int32)
@@ -382,7 +393,7 @@ def _branch_candidate(g: GraphContext, cfg: EngineConfig,
         part_row = partw
         has_part = jnp.any(partw != 0)
     else:
-        qb = bitset.to_bool(s.qmask[lvl], cfg.n_u)
+        qb = bitset.to_bool(q_row, cfg.n_u)
         pb = bitset.to_bool(pm_after, cfg.n_u)
         c2 = intersect_count(g.adj, Lp, impl=cfg.impl)         # (NU,)
         viol = jnp.any(qb & (c2 == nLp)) & nonempty
@@ -392,7 +403,7 @@ def _branch_candidate(g: GraphContext, cfg: EngineConfig,
         c_row = c2
         q_keep = bitset.from_bool(c2 > 0)
     is_max = nonempty & ~viol
-    Rp = s.rmask[lvl] | bitset.singleton(x, cfg.wu) | fullw
+    Rp = _row(s.rmask, lvl) | bitset.singleton(x, cfg.wu) | fullw
     has_child = is_max & has_part
 
     # -- descend / finish -------------------------------------------------
@@ -400,11 +411,11 @@ def _branch_candidate(g: GraphContext, cfg: EngineConfig,
     # task terminates once its subtree is done (other roots are other tasks)
     pm_final = jnp.where(forced, jnp.zeros_like(pm_after), pm_after)
     # paper's Q' filter comes free from the shared counts/check pass:
-    q_child = s.qmask[lvl] & q_keep
+    q_child = q_row & q_keep
     nl = jnp.where(has_child, lvl + 1, lvl)
     child = jnp.minimum(lvl + 1, cfg.depth - 1)
     # no child: x's subtree is finished -> move x to Q at this level
-    q_lvl = bitset.add(s.qmask[lvl], jnp.maximum(x, 0))
+    q_lvl = bitset.add(q_row, jnp.maximum(x, 0))
 
     return _delta_zeros(cfg, s)._replace(
         l_row=Lp, l_idx=child, l_en=has_child,
@@ -426,8 +437,10 @@ def _branch_candidate(g: GraphContext, cfg: EngineConfig,
 
 def _apply_delta(cfg: EngineConfig, s: DenseState, d: Delta) -> DenseState:
     def setrow(stack, row, idx, en):
-        i = jnp.clip(idx, 0, stack.shape[0] - 1)
-        return stack.at[i].set(jnp.where(en, row, stack[i]))
+        # a disabled write goes out of range and is dropped
+        i = jnp.where(en, jnp.clip(idx, 0, stack.shape[0] - 1),
+                      stack.shape[0])
+        return stack.at[i].set(row, mode="drop")
 
     lmask = setrow(s.lmask, d.l_row, d.l_idx, d.l_en)
     cstack = setrow(s.cstack, d.c_row, d.child, d.l_en | (d.tpos > s.tpos))
@@ -435,14 +448,12 @@ def _apply_delta(cfg: EngineConfig, s: DenseState, d: Delta) -> DenseState:
     pmask = setrow(pmask, d.pb_row, d.child, d.l_en | (d.tpos > s.tpos))
     qmask = setrow(s.qmask, d.q_row, d.q_idx, d.q_en)
     rmask = setrow(s.rmask, d.r_row, d.child, d.l_en | (d.tpos > s.tpos))
-    xstack = s.xstack.at[jnp.clip(d.x_idx, 0, cfg.depth - 1)].set(
-        jnp.where(d.x_en, d.x_val, s.xstack[jnp.clip(d.x_idx, 0,
-                                                     cfg.depth - 1)]))
+    xstack = setrow(s.xstack, d.x_val, d.x_idx, d.x_en)
     C = cfg.collect_cap
     w_idx = jnp.minimum(s.out_n, C - 1)
     write = d.ow_en & (s.out_n < C)
-    out_l = s.out_l.at[w_idx].set(jnp.where(write, d.ow_l, s.out_l[w_idx]))
-    out_r = s.out_r.at[w_idx].set(jnp.where(write, d.ow_r, s.out_r[w_idx]))
+    out_l = setrow(s.out_l, d.ow_l, w_idx, write)
+    out_r = setrow(s.out_r, d.ow_r, w_idx, write)
     return s._replace(
         lmask=lmask, cstack=cstack, pmask=pmask, qmask=qmask, rmask=rmask,
         xstack=xstack, lvl=d.lvl, forced_x=d.forced_x, tpos=d.tpos,
@@ -459,7 +470,7 @@ def _apply_delta(cfg: EngineConfig, s: DenseState, d: Delta) -> DenseState:
 def _case_id(cfg: EngineConfig, s: DenseState) -> jax.Array:
     """0 = backtrack, 1 = init next task, 2 = process a candidate."""
     lvl_safe = jnp.maximum(s.lvl, 0)
-    p_empty = bitset.count(s.pmask[lvl_safe]) == 0
+    p_empty = bitset.count(_row(s.pmask, lvl_safe)) == 0
     return jnp.where(
         s.lvl < 0, 1,
         jnp.where(p_empty & (s.forced_x < 0), 0, 2)).astype(jnp.int32)
@@ -469,15 +480,44 @@ def _done(s: DenseState) -> jax.Array:
     return (s.lvl < 0) & (s.tpos >= s.n_tasks)
 
 
+_BRANCHES = (_branch_backtrack, _branch_init_task, _branch_candidate)
+
+
 def step(g: GraphContext, cfg: EngineConfig, s: DenseState) -> DenseState:
     s = s._replace(steps=s.steps + 1)
     delta = jax.lax.switch(
         _case_id(cfg, s),
-        [lambda st: _branch_backtrack(g, cfg, st),
-         lambda st: _branch_init_task(g, cfg, st),
-         lambda st: _branch_candidate(g, cfg, st)],
+        [lambda st, b=b: b(g, cfg, st) for b in _BRANCHES],
         s)
     return _apply_delta(cfg, s, delta)
+
+
+def _masked_step(g: GraphContext, cfg: EngineConfig, s: DenseState,
+                 en: jax.Array) -> DenseState:
+    """``step`` of one lane of a batch, taken only where ``en``.
+
+    Made to run under ``vmap``: the three branches' deltas are combined
+    by a row-sized select on the case id (what a batched ``lax.switch``
+    computes anyway), and every row write, scalar and counter of the
+    delta is gated by ``en``.  So no select reads a whole stack, and each
+    row scatter updates its stack in place.  Where ``en`` is false the
+    lane's state comes back bit for bit."""
+    s = s._replace(steps=s.steps + en.astype(jnp.int32))
+    cid = _case_id(cfg, s)
+    d = jax.tree.map(lambda *xs: jax.lax.select_n(cid, *xs),
+                     *(b(g, cfg, s) for b in _BRANCHES))
+
+    def keep(new, old):
+        return jnp.where(en, new, old)
+    d = d._replace(
+        l_en=d.l_en & en, pa_en=d.pa_en & en, q_en=d.q_en & en,
+        x_en=d.x_en & en, ow_en=d.ow_en & en,
+        lvl=keep(d.lvl, s.lvl), forced_x=keep(d.forced_x, s.forced_x),
+        tpos=keep(d.tpos, s.tpos),
+        nodes_inc=keep(d.nodes_inc, 0), n_max_inc=keep(d.n_max_inc, 0),
+        max_fail_inc=keep(d.max_fail_inc, 0),
+        cs_inc=keep(d.cs_inc, jnp.uint32(0)))
+    return _apply_delta(cfg, s, d)
 
 
 def run(g: GraphContext, cfg: EngineConfig, s: DenseState,
@@ -611,6 +651,46 @@ def _run_batch_pool(g: GraphContext, cfg: EngineConfig, s: DenseState,
     return out
 
 
+def stepwise_lanes(cfg: EngineConfig, batch: int) -> bool:
+    """Whether ``run_batch`` advances ``batch`` lanes on the lane-masked
+    per-step loop (``_run_batch_stepwise``): neither the pool kernel nor
+    ``batch`` concurrent resident lanes fit."""
+    if pool_lanes(cfg, batch):
+        return False
+    return not (cfg.resident_active and resident_supported(cfg, lanes=batch))
+
+
+def _run_batch_stepwise(g: GraphContext, cfg: EngineConfig, s: DenseState,
+                        budget: int, ctx_batched: bool,
+                        unroll: int) -> DenseState:
+    """Per-step backing for ``run_batch``: ONE unbatched while loop, run
+    until no lane is active, whose body takes ``unroll`` lane-masked
+    steps (``_masked_step``) under ``vmap``.
+
+    A step is taken by exactly the lanes for which ``~done & (steps -
+    start < budget)`` holds before it, the predicate of ``run``'s loop
+    and of its guarded unrolled steps, so the trajectory is byte-identical
+    to ``vmap(run)``.  ``vmap(run)`` turns the batched loop carry, each
+    guarded step's ``lax.cond`` and the step's ``lax.switch`` into
+    selects over whole stacks, which a TPU also copied between layouts
+    every step; here every select is row-sized and every stack is
+    written only by row scatters.
+    """
+    start = s.steps
+
+    def active(st, st0):
+        return (~_done(st)) & (st.steps - st0 < budget)
+
+    def segment(c, st, st0):
+        for _ in range(unroll):
+            st = _masked_step(c, cfg, st, active(st, st0))
+        return st
+
+    seg = jax.vmap(segment, in_axes=(0 if ctx_batched else None, 0, 0))
+    return jax.lax.while_loop(lambda st: jnp.any(active(st, start)),
+                              lambda st: seg(g, st, start), s)
+
+
 def run_batch(g: GraphContext, cfg: EngineConfig, s: DenseState,
               max_steps: int | None = None,
               ctx_batched: bool = False, unroll: int = 1) -> DenseState:
@@ -628,25 +708,24 @@ def run_batch(g: GraphContext, cfg: EngineConfig, s: DenseState,
 
     On the resident pallas path the batch is advanced by the multi-lane
     pool kernel whenever ``pool_lanes`` admits it — one launch per
-    segment for the WHOLE pool instead of B vmapped launches.  Otherwise
-    ``vmap`` lifts the engine's ``while_loop`` to run until every lane
-    is done, masking finished lanes.  Either way one jitted call
-    enumerates the whole batch, and the compiled executable depends only
-    on the bucket shape and ``cfg``, never on the graphs themselves (the
-    serving cache's key).
-
-    The vmap fallback applies a batch-aware residency gate: B concurrent
-    single-lane launches pin B state blocks, so when
-    ``resident_supported(cfg, lanes=B)`` fails the batch drops to the
-    per-step fused kernels (byte-identical, still pallas) instead of
-    overcommitting VMEM.
+    segment for the WHOLE pool instead of B vmapped launches.  When B
+    concurrent single-lane resident launches fit instead
+    (``resident_supported(cfg, lanes=B)``), ``vmap`` lifts ``run``'s
+    while loop to run until every lane is done, masking finished lanes.
+    Otherwise (``stepwise_lanes``: the jnp path, or a pallas batch too
+    large to keep resident) the batch takes the per-step kernels in one
+    unbatched loop of lane-masked steps (``_run_batch_stepwise``).  All
+    three are byte-identical.  Either way one jitted call enumerates the
+    whole batch, and the compiled executable depends only on the bucket
+    shape and ``cfg``, never on the graphs themselves (the serving
+    cache's key).
     """
     B = s.lvl.shape[0]
     budget = cfg.max_steps if max_steps is None else max_steps
     if pool_lanes(cfg, B):
         return _run_batch_pool(g, cfg, s, budget, ctx_batched, unroll)
-    if cfg.resident_active and not resident_supported(cfg, lanes=B):
-        cfg = dataclasses.replace(cfg, resident=False)
+    if stepwise_lanes(cfg, B):
+        return _run_batch_stepwise(g, cfg, s, budget, ctx_batched, unroll)
     ax = 0 if ctx_batched else None
     return jax.vmap(
         lambda c, st: run(c, cfg, st, max_steps=max_steps, unroll=unroll),

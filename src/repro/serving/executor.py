@@ -275,6 +275,12 @@ class Executor(abc.ABC):
         this by the segments a round actually ran."""
         return 1 if pool.engine.pool_lanes(pool.cfg, pool.B) else pool.B
 
+    def stepwise(self, pool: LanePool) -> bool:
+        """Whether this pool's rounds take the engine's lane-masked
+        per-step loop (``Engine.stepwise_lanes`` at the width
+        ``run_batch`` sees)."""
+        return pool.engine.stepwise_lanes(pool.cfg, pool.B)
+
     # -- demux views ----------------------------------------------------
     def lane(self, pool: LanePool, i: int) -> ed.DenseState:
         """Host-readable view of one lane's state (for demux)."""
@@ -427,6 +433,10 @@ class ShardedExecutor(Executor):
         wpd = pool.B // self.n_devices
         per_dev = 1 if pool.engine.pool_lanes(pool.cfg, wpd) else wpd
         return self.n_devices * per_dev
+
+    def stepwise(self, pool: LanePool) -> bool:
+        return pool.engine.stepwise_lanes(pool.cfg,
+                                          pool.B // self.n_devices)
 
     def placement(self, n_lanes: int) -> str:
         wpd = n_lanes // self.n_devices

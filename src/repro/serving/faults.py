@@ -201,6 +201,9 @@ class FaultInjector(Executor):
     def launches_per_segment(self, pool):
         return self.inner.launches_per_segment(pool)
 
+    def stepwise(self, pool):
+        return self.inner.stepwise(pool)
+
     def _pool_sharding(self):
         return self.inner._pool_sharding()
 

@@ -176,7 +176,8 @@ STATS_SCHEMA: dict[str, type | tuple] = dict(
     hits=int, misses=int, entries=int, evictions=int,
     poll_s=float, refill_s=float, demux_s=float, exec_s=float,
     installs=int, install_fallbacks=int,
-    gathered_select_words=int, gathered_check_words=int)
+    gathered_select_words=int, gathered_check_words=int,
+    stepwise_steps=int)
 
 # Monotonic counters (reset by ``MBEServer.reset_stats``); everything
 # else in STATS_SCHEMA is a gauge or a configuration echo.
@@ -190,7 +191,7 @@ MONOTONIC_STATS = frozenset((
     "hits", "misses", "evictions",
     "poll_s", "refill_s", "demux_s", "exec_s",
     "installs", "install_fallbacks",
-    "gathered_select_words", "gathered_check_words"))
+    "gathered_select_words", "gathered_check_words", "stepwise_steps"))
 
 # The kernel passes whose work an engine may count (``Engine.work_rows``),
 # reported in stats() as ``<pass>_words``.
@@ -334,6 +335,8 @@ class _LanePool:
         server._n_rounds += 1
         server._busy_steps += busy
         server._total_lane_steps += self.B * crit
+        if server.executor.stepwise(self.pool):
+            server._stepwise_steps += busy
         server._exec_s += exec_s
         server._add_work(tel, self.cfg)
         # launch accounting: the round's critical path ran ceil(crit/spc)
@@ -512,6 +515,7 @@ class MBEServer:
         self._n_installs = 0            # refills by the install executable
         self._n_install_fallbacks = 0   # lanes placed by row surgery
         self._work_words = dict.fromkeys(WORK_PASSES, 0)
+        self._stepwise_steps = 0       # lane steps of per-step rounds
         self._n_launches = 0
         self._rebalanced_steps = 0
         self._n_cancelled = 0
@@ -819,6 +823,8 @@ class MBEServer:
         wpd = slot.lane.n_workers // n_dev
         pw = self.engine.pool_lanes(slot.lane.cfg, wpd)
         self._n_launches += segments * n_dev * (1 if pw else wpd)
+        if self.engine.stepwise_lanes(slot.lane.cfg, wpd):
+            self._stepwise_steps += busy
         if self._big_busy_per_worker is None:
             self._big_busy_per_worker = np.zeros(slot.lane.n_workers,
                                                  np.int64)
@@ -1459,6 +1465,9 @@ class MBEServer:
                     # adjacency words each kernel pass had to read
                     # (Engine.work_rows; 0 for engines that count none)
                     **{f"{k}_words": v for k, v in self._work_words.items()},
+                    # lane steps advanced on the dense engine's
+                    # lane-masked per-step loop (Engine.stepwise_lanes)
+                    stepwise_steps=self._stepwise_steps,
                     **self.cache.stats())
 
     def reset_stats(self) -> None:
@@ -1479,7 +1488,8 @@ class MBEServer:
         the host time counters ``poll_s``, ``refill_s``, ``demux_s``,
         ``exec_s``, the placement counters ``installs`` and
         ``install_fallbacks``, the kernel work counters
-        ``gathered_select_words`` and ``gathered_check_words``, and the
+        ``gathered_select_words`` and ``gathered_check_words``, the
+        per-step loop's ``stepwise_steps``, and the
         cache counters ``hits``/``misses``/
         ``evictions`` (so the miss count stays an honest per-phase
         compile count).
@@ -1502,6 +1512,7 @@ class MBEServer:
         self._n_installs = 0
         self._n_install_fallbacks = 0
         self._work_words = dict.fromkeys(WORK_PASSES, 0)
+        self._stepwise_steps = 0
         self._n_launches = 0
         self._rebalanced_steps = 0
         self._n_cancelled = 0

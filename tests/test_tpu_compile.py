@@ -173,3 +173,52 @@ def test_compact_round_compiles_at_the_ucforum_bucket(one_chip, monkeypatch):
         _tree_spec(one_chip, ctx, (lanes,)),
         _tree_spec(one_chip, state, (lanes,)))
     jax.clear_caches()
+
+
+def _loop_ops_on(text: str, shape: str) -> list[str]:
+    """Opcodes of the instructions outside the entry computation (loop
+    bodies and the fusions they call) whose result has type ``shape``."""
+    ops, entry = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        elif not entry and f"= {shape}{{" in line:
+            ops.append(line.split("= ", 1)[1].split(" ", 1)[1]
+                       .split("(", 1)[0])
+    return ops
+
+
+def test_dense_round_at_the_ucforum_bucket_moves_no_whole_stack(
+        one_chip, monkeypatch):
+    """The dense engine's served round for KONECT opsahl-ucforum's pow2
+    bucket at 8 lanes: the per-step path (neither the pool kernel nor 8
+    resident lanes fit), 512 steps a round, 8 steps per call, Mosaic
+    kernels.  Inside the loop the counts cache ``cstack``
+    (``s32[8,1026,1024]``, 33.6 MB) is touched only by row scatters: no
+    copy into a second layout and no select of the whole stack."""
+    from repro.core.engine import DENSE
+    from repro.core.graph import BipartiteGraph
+    from repro.kernels.fused_check import ops as check_ops
+    from repro.kernels.fused_select import ops as select_ops
+    from repro.serving.buckets import BucketPolicy, plan_bucket
+    for ops in (select_ops, check_ops):
+        monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    jax.clear_caches()              # no trace kept from interpret mode
+    g = BipartiteGraph.from_edges(522, 899, [(0, 0)])
+    bucket = plan_bucket(g, BucketPolicy(mode="pow2", max_batch=8))
+    cfg = DENSE.config(bucket.n_u, bucket.n_v, bucket.depth,
+                       kernel_impl="pallas")
+    lanes = 8
+    assert DENSE.stepwise_lanes(cfg, lanes)
+    ctx = jax.eval_shape(lambda: DENSE.dummy_context(cfg))
+    state = jax.eval_shape(lambda: DENSE.fresh_lane_state(cfg, 0))
+    text = jax.jit(lambda c, s: DENSE.run_batch(
+        c, cfg, s, max_steps=512, ctx_batched=True, unroll=8)).lower(
+        _tree_spec(one_chip, ctx, (lanes,)),
+        _tree_spec(one_chip, state, (lanes,))).compile().as_text()
+    jax.clear_caches()
+    assert "tpu_custom_call" in text
+    ops = _loop_ops_on(text, f"s32[{lanes},{cfg.depth},{cfg.n_u}]")
+    assert ops.count("scatter") == 8, ops
+    assert set(ops) <= {"parameter", "get-tuple-element", "fusion",
+                        "scatter"}, sorted(set(ops))
