@@ -119,12 +119,6 @@ class MBEOptions:
     #                               VMEM gate admits it; int k >= 2 caps
     #                               the pool width; 0/1 pins the legacy
     #                               one-launch-per-lane vmap layout
-    resident_rebalance: bool = False     # pool path: reassign surplus
-    #                               step budget from finished lanes to
-    #                               busy ones at segment boundaries (the
-    #                               scoreboard rebalance; trajectory
-    #                               intentionally diverges from the
-    #                               fixed-budget vmap path)
 
     # -- shape bucketing / batching (serving.buckets.BucketPolicy) -----
     bucket_mode: str = "pow2"     # 'pow2' | 'linear' | 'exact'
@@ -235,7 +229,6 @@ class MBEOptions:
             engine=get_engine(self.engine),
             engine_params=self.engine_params(),
             resident_lanes=self.resident_lanes,
-            resident_rebalance=self.resident_rebalance,
             admission=self.admission,
             trace_path=self.trace_path,
             retry=self.retry,

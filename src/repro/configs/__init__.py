@@ -1,83 +1,3 @@
-"""Architecture registry: ``--arch <id>`` resolves here.
-
-Each module defines CONFIG (the exact published config) and SMOKE (a
-reduced same-family config for CPU tests). ``input_specs`` builds the
-ShapeDtypeStruct stand-ins for every model input of an (arch x shape)
-cell — the dry-run lowers against these, no allocation.
-"""
-from __future__ import annotations
-
-import importlib
-import math
-
-import jax
-import jax.numpy as jnp
-
-from repro.models.config import ModelConfig, ShapeSpec, SHAPES  # noqa: F401
-
-_MODULES = {
-    "qwen3-1.7b": "qwen3_1_7b",
-    "granite-3-8b": "granite_3_8b",
-    "llama3.2-3b": "llama3_2_3b",
-    "llama3-8b": "llama3_8b",
-    "dbrx-132b": "dbrx_132b",
-    "granite-moe-1b-a400m": "granite_moe_1b_a400m",
-    "internvl2-2b": "internvl2_2b",
-    "musicgen-medium": "musicgen_medium",
-    "zamba2-7b": "zamba2_7b",
-    "xlstm-1.3b": "xlstm_1_3b",
-    "cumbe": "cumbe",            # the paper's own workload
-}
-
-ARCH_IDS = [k for k in _MODULES if k != "cumbe"]
-
-
-def _mod(arch: str):
-    if arch not in _MODULES:
-        raise KeyError(f"unknown arch {arch!r}; known: {sorted(_MODULES)}")
-    return importlib.import_module(f"repro.configs.{_MODULES[arch]}")
-
-
-def get_config(arch: str) -> ModelConfig:
-    return _mod(arch).CONFIG
-
-
-def get_smoke(arch: str) -> ModelConfig:
-    return _mod(arch).SMOKE
-
-
-def round_up(x: int, mult: int) -> int:
-    return (x + mult - 1) // mult * mult
-
-
-def cache_len(cfg: ModelConfig, shape: ShapeSpec) -> int:
-    """Decode KV-cache capacity: seq (+ vlm patch prefix), padded so any
-    sequence sharding in the production meshes divides."""
-    return round_up(shape.seq_len + cfg.patch_tokens, 1024)
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeSpec) -> dict:
-    """ShapeDtypeStructs for every input of (arch x shape); weak-type
-    correct, shardable, zero device allocation."""
-    from repro.models import model as M
-
-    B, S = shape.global_batch, shape.seq_len
-    i32 = jnp.int32
-    tok_shape = (B, S, cfg.n_codebooks) if cfg.n_codebooks else (B, S)
-
-    if shape.kind in ("train", "prefill"):
-        specs = {"tokens": jax.ShapeDtypeStruct(tok_shape, i32)}
-        if shape.kind == "train":
-            specs["labels"] = jax.ShapeDtypeStruct(tok_shape, i32)
-        if cfg.family == "vlm":
-            specs["patch_emb"] = jax.ShapeDtypeStruct(
-                (B, cfg.patch_tokens, cfg.d_model), jnp.dtype(cfg.dtype))
-        return specs
-
-    assert shape.kind == "decode"
-    tok = (B, cfg.n_codebooks) if cfg.n_codebooks else (B,)
-    return {
-        "cache": M.cache_specs(cfg, B, cache_len(cfg, shape)),
-        "tokens": jax.ShapeDtypeStruct(tok, i32),
-        "pos": jax.ShapeDtypeStruct((), i32),
-    }
+"""Workload configs: ``cumbe`` holds the paper's graph-scale classes
+(``CONFIG``, the 16k x 16k production class; ``SMOKE``, its CPU-sized
+twin)."""

@@ -1,16 +1,16 @@
-"""The paper's own workload config: distributed MBE on the production mesh.
+"""The paper's graph-scale classes for distributed MBE.
 
-This is the framework's first-class feature (DESIGN.md §1). The "shape"
-analog of an LM workload is a graph-scale class; the dry-run lowers the
-distributed round function (engine while_loop + work-stealing collective)
-for the production meshes exactly like an LM train_step.
+A workload is a graph-scale class: padded sizes, generator density, DFS
+depth bound and the work-stealing round settings (``DistConfig``).
+``CONFIG`` is the production class that ``chip_smoke.py`` serves on the
+chip; ``SMOKE`` is its CPU-sized twin, whose worker count
+``launch/mbe_run.py`` takes as its default.
 """
 from __future__ import annotations
 
 import dataclasses
 
 from repro.core.distributed import DistConfig
-from repro.core.engine_dense import EngineConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -22,12 +22,8 @@ class MBEWorkload:
     depth: int               # DFS depth bound
     dist: DistConfig = DistConfig()
 
-    def engine_config(self, impl: str = "jnp") -> EngineConfig:
-        return EngineConfig(n_u=self.n_u, n_v=self.n_v, m_real=self.n_u,
-                            depth=self.depth, impl=impl)
 
-
-# Production-scale MBE cell lowered by the dry-run. |U|=16384 bitset rows x
+# Production-scale MBE class. |U|=16384 bitset rows x
 # |V|=16384 -> adjacency 16384 x 512 u32 words = 32 MiB resident per device
 # (replicated graph, sharded root tasks) — the paper's Table-I scale class.
 CONFIG = MBEWorkload(

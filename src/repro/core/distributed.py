@@ -90,30 +90,6 @@ def _deal_strided(flat: jax.Array, total: jax.Array, w: jax.Array,
     return tasks.astype(jnp.int32), n
 
 
-def context_specs(cfg: ed.EngineConfig) -> ed.GraphContext:
-    """ShapeDtypeStructs for the device-resident graph (dry-run lowering).
-
-    DENSE-ENGINE ONLY: ``launch/dryrun.py``'s lowering helper.  The
-    serving stack never calls this — per-engine context shapes come from
-    ``Engine.dummy_context``/``make_context``."""
-    return ed.GraphContext(
-        adj=jax.ShapeDtypeStruct((cfg.n_u, cfg.wv), jnp.uint32),
-        order=jax.ShapeDtypeStruct((cfg.n_u,), jnp.int32),
-        rank=jax.ShapeDtypeStruct((cfg.n_u,), jnp.int32),
-        l_root=jax.ShapeDtypeStruct((cfg.wv,), jnp.uint32),
-        root_counts=jax.ShapeDtypeStruct((cfg.n_u,), jnp.int32))
-
-
-def state_specs(cfg: ed.EngineConfig, n_workers: int) -> ed.DenseState:
-    """ShapeDtypeStructs of the stacked worker state (dim0 = workers).
-
-    DENSE-ENGINE ONLY, like ``context_specs`` (dry-run helper)."""
-    s = jax.eval_shape(lambda: ed.init_state(
-        cfg, np.zeros(cfg.m_real, np.int32)))
-    return jax.tree.map(
-        lambda l: jax.ShapeDtypeStruct((n_workers,) + l.shape, l.dtype), s)
-
-
 def make_round_fn(cfg: ed.EngineConfig, mesh: Mesh,
                   axis_names: tuple[str, ...],
                   dist: DistConfig = DistConfig(),
@@ -122,9 +98,8 @@ def make_round_fn(cfg: ed.EngineConfig, mesh: Mesh,
                   engine=None):
     """The jitted work-stealing round: (ctx, state) -> state.
 
-    Graph context is an explicit argument (replicated over the mesh) so the
-    dry-run can lower against ShapeDtypeStructs — no 32 MiB adjacency
-    constant baked into the HLO.
+    Graph context is an explicit argument (replicated over the mesh), so
+    no 32 MiB adjacency constant is baked into the HLO.
 
     ``ctx_batched=False`` (default): one graph, replicated; every worker
     lane runs its task slice of that graph and pending tasks are stolen

@@ -111,14 +111,6 @@ class EngineConfig:
     #                             passes; int k >= 2 = only for pools up
     #                             to k lanes; 0/1 = never (legacy
     #                             vmap-of-single-lane)
-    resident_rebalance: bool = False     # pool path only: at each segment
-    #                             boundary, reassign surplus step budget
-    #                             from finished lanes to busy ones via
-    #                             the kernel's scoreboard (host-side
-    #                             first iteration of in-kernel stealing).
-    #                             Off by default — it intentionally
-    #                             diverges from the fixed-budget vmap
-    #                             trajectory
 
     @property
     def fused(self) -> bool:
@@ -588,33 +580,6 @@ def pool_lanes(cfg: EngineConfig, batch: int) -> int:
     return batch if resident_pool_supported(cfg, batch) else 0
 
 
-# per-lane donations are clamped well under int32 range before summing,
-# so a pool of default-budget (1 << 30) finished lanes cannot overflow
-# the surplus accumulator
-_REBALANCE_CLAMP = np.int32(1 << 24)
-
-
-def _rebalance_budgets(start: jax.Array, bud: jax.Array, st: DenseState,
-                       board: jax.Array) -> jax.Array:
-    """Round-boundary budget rebalance from the pool scoreboard.
-
-    Finished lanes donate their unused budget (``bud - used``, clamped);
-    the surplus is split evenly (floor) over busy lanes, so the total
-    granted never exceeds the total donated — the step budget is
-    conserved.  Finished lanes are frozen at ``used``: their remaining
-    budget reads zero in every later round (no double donation) and the
-    kernel's done guard keeps them from advancing regardless.
-    """
-    used = st.steps - start
-    finished = board[:, 0] > 0
-    rem = jnp.clip(bud - used, 0, _REBALANCE_CLAMP)
-    surplus = jnp.sum(jnp.where(finished, rem, 0))
-    n_busy = jnp.maximum(jnp.sum((~finished).astype(jnp.int32)), 1)
-    grant = surplus // n_busy
-    new_bud = jnp.where(finished, used, bud + grant)
-    return jnp.minimum(new_bud, jnp.int32(1 << 30))
-
-
 def _run_batch_pool(g: GraphContext, cfg: EngineConfig, s: DenseState,
                     budget: int, ctx_batched: bool,
                     unroll: int) -> DenseState:
@@ -627,28 +592,21 @@ def _run_batch_pool(g: GraphContext, cfg: EngineConfig, s: DenseState,
     active)`` with a masked body, and the pool kernel applies the same
     per-lane ``~done & (steps - start < budget)`` guard internally —
     exactly the predicate below, with per-lane ``start``/``budget``
-    columns.  With ``cfg.resident_rebalance`` the budgets become mutable
-    loop state fed from the scoreboard (and the trajectory intentionally
-    diverges from the fixed-budget vmap path).
+    columns.  The segment's scoreboard is not read here.
     """
     start = s.steps
-    bud0 = jnp.full_like(start, jnp.int32(budget))
+    bud = jnp.full_like(start, jnp.int32(budget))
 
-    def cond(carry):
-        st, bud = carry
+    def cond(st):
         return jnp.any((~_done(st)) & (st.steps - start < bud))
 
-    def body(carry):
-        st, bud = carry
-        st2, board = resident_pool_segment(
+    def body(st):
+        st2, _ = resident_pool_segment(
             g, cfg, st, start=start, budget=bud, steps_per_call=unroll,
             ctx_batched=ctx_batched)
-        if cfg.resident_rebalance:
-            bud = _rebalance_budgets(start, bud, st2, board)
-        return st2, bud
+        return st2
 
-    out, _ = jax.lax.while_loop(cond, body, (s, bud0))
-    return out
+    return jax.lax.while_loop(cond, body, s)
 
 
 def stepwise_lanes(cfg: EngineConfig, batch: int) -> bool:

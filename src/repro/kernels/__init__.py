@@ -1,4 +1,4 @@
-"""Pallas TPU kernels for the framework's compute hot-spots.
+"""Pallas TPU kernels for the MBE engines' compute hot-spots.
 
 Each kernel package ships kernel.py (pl.pallas_call + explicit BlockSpec
 VMEM tiling), ops.py (jit'd dispatch wrapper) and ref.py (pure-jnp
@@ -13,9 +13,6 @@ and lower natively on TPU.
   fused_check      — counts + Q-violation flag + full/partial expansion
                      partition in one pass: the rest of an enumeration
                      step (phases C/E), counts never round-tripped to HBM
-  flash_attention  — fwd + custom-vjp bwd flash attention for the LM
-                     stack (GQA, causal tile skipping); the dominant
-                     memory-roofline term of every train/prefill cell
 
 ``dispatch.resolve_impl`` is the shared "auto"|"jnp"|"pallas" rule every
 op (and the engines' ``EngineConfig.kernel_impl``) resolves through.

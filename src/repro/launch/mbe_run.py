@@ -7,8 +7,8 @@ ONE request routed to the work-stealing big-graph lane
 device x ``--workers`` stealing workers — exactly the decomposition the
 old hand-wired ``make_distributed_runner`` path built, now behind the
 same front door the serving stack uses.  (The multi-device run is
-exercised with simulated devices in tests; the production-mesh lowering
-is dryrun.py's cumbe cell.)
+exercised with simulated devices in tests; ``chip_smoke.py`` serves the
+cumbe-16k class on the chip.)
 
 Usage:
   python -m repro.launch.mbe_run --dataset marvel-like --workers 2
@@ -69,9 +69,6 @@ def main(argv=None) -> dict:
                          "'auto' = one launch per worker pool whenever "
                          "the VMEM gate admits it, int k caps the pool "
                          "width, 0/1 pins the legacy vmap layout")
-    ap.add_argument("--resident-rebalance", action="store_true",
-                    help="pool path: rebalance surplus step budget from "
-                         "finished to busy workers at segment boundaries")
     ap.add_argument("--no-work-stealing", action="store_true")
     ap.add_argument("--order", default="deg", choices=["deg", "input"])
     ap.add_argument("--verbose", action="store_true")
@@ -100,7 +97,6 @@ def main(argv=None) -> dict:
         count_p=args.count_p, count_q=args.count_q,
         kernel_impl=args.kernel_impl,
         resident_lanes=args.resident_lanes,
-        resident_rebalance=args.resident_rebalance,
         bucket_mode="exact",            # one graph: no padding wanted
         big_graph_threshold=1,          # the whole run IS the big route
         steps_per_round=args.steps_per_round,

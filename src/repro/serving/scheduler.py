@@ -3,8 +3,8 @@ routed across pluggable execution backends.
 
 ``MBEServer`` is the serving front end: users ``submit``/``admit``
 bipartite graphs (one request = one whole graph to enumerate) and the
-scheduler serves them through per-bucket **lane pools** — the LM serving
-loop's slot model applied to graph lanes.
+scheduler serves them through per-bucket **lane pools** — the slot model
+of continuous-batching LM servers, applied to graph lanes.
 
 The slot model
 --------------
@@ -166,7 +166,7 @@ STATS_SCHEMA: dict[str, type | tuple] = dict(
     busy_steps=int, total_lane_steps=int, idle_lane_steps=int,
     occupancy=float, kernel_impl=str, steps_per_call=int,
     steps_per_poll=float, resident_lanes=(int, str), launches=int,
-    launches_per_poll=float, rebalanced_steps=int, executor=str,
+    launches_per_poll=float, executor=str,
     engine=str, cancelled=int, timed_out=int,
     admitted=int, rejected=int, shed=int, rejected_backpressure=int,
     rejected_fairness=int, per_tenant=dict,
@@ -183,7 +183,7 @@ STATS_SCHEMA: dict[str, type | tuple] = dict(
 # else in STATS_SCHEMA is a gauge or a configuration echo.
 MONOTONIC_STATS = frozenset((
     "batches", "lanes", "pad_lanes", "busy_steps", "total_lane_steps",
-    "idle_lane_steps", "launches", "rebalanced_steps", "cancelled",
+    "idle_lane_steps", "launches", "cancelled",
     "timed_out", "admitted", "rejected", "shed",
     "rejected_backpressure", "rejected_fairness",
     "failed", "step_capped", "retries", "faults_injected",
@@ -346,11 +346,6 @@ class _LanePool:
         segments = (crit + spc - 1) // spc
         server._n_launches += \
             segments * server.executor.launches_per_segment(self.pool)
-        if server.resident_rebalance and budget is not None:
-            # steps a lane ran beyond its own round budget came from
-            # donated surplus (the scoreboard rebalance)
-            server._rebalanced_steps += int(np.maximum(adv - budget,
-                                                       0).sum())
         for i, r in enumerate(self.reqs):
             if r is None:
                 continue
@@ -450,7 +445,6 @@ class MBEServer:
                  engine: str | Engine = "dense",
                  engine_params: dict | None = None,
                  resident_lanes: int | str = "auto",
-                 resident_rebalance: bool = False,
                  admission: AdmissionController | AdmissionPolicy
                  | None = None,
                  trace_path: str | None = None,
@@ -466,7 +460,6 @@ class MBEServer:
         self.impl = impl
         self.kernel_impl = kernel_impl
         self.resident_lanes = resident_lanes
-        self.resident_rebalance = resident_rebalance
         self.max_graph_steps = max_graph_steps
         self.strict_step_cap = strict_step_cap
         self.executor = executor or LocalExecutor()
@@ -517,7 +510,6 @@ class MBEServer:
         self._work_words = dict.fromkeys(WORK_PASSES, 0)
         self._stepwise_steps = 0       # lane steps of per-step rounds
         self._n_launches = 0
-        self._rebalanced_steps = 0
         self._n_cancelled = 0
         self._n_timed_out = 0
         self._n_failed = 0
@@ -693,7 +685,6 @@ class MBEServer:
             collect_cap=self.collect_cap, order_mode=self.order_mode,
             impl=self.impl, kernel_impl=self.kernel_impl,
             resident_lanes=self.resident_lanes,
-            resident_rebalance=self.resident_rebalance,
             **self.engine_params)
 
     def _round_budget(self) -> int | None:
@@ -1402,15 +1393,14 @@ class MBEServer:
                     steps_per_call=self.policy.steps_per_call,
                     steps_per_poll=(self._busy_steps / self._n_rounds
                                     if self._n_rounds else 0.0),
-                    # the pool-kernel knob + its launch-amortization and
-                    # rebalance ledgers (launches counts kernel dispatches
+                    # the pool-kernel knob + its launch-amortization
+                    # ledger (launches counts kernel dispatches
                     # on the resident path: 1 per segment per pool when
                     # the multi-lane kernel is active, 1 per lane on vmap)
                     resident_lanes=self.resident_lanes,
                     launches=self._n_launches,
                     launches_per_poll=(self._n_launches / self._n_rounds
                                        if self._n_rounds else 0.0),
-                    rebalanced_steps=self._rebalanced_steps,
                     executor=self.executor.name,
                     engine=self.engine.name,
                     cancelled=self._n_cancelled,
@@ -1479,9 +1469,9 @@ class MBEServer:
         Monotonic keys reset here: ``batches``, ``lanes``,
         ``pad_lanes``, ``busy_steps``, ``total_lane_steps``,
         ``idle_lane_steps``, ``occupancy``, ``steps_per_poll``,
-        ``launches``, ``launches_per_poll``, ``rebalanced_steps``,
-        ``cancelled``, ``timed_out``, ``admitted``, ``rejected``,
-        ``shed``, ``rejected_backpressure``, ``rejected_fairness``,
+        ``launches``, ``launches_per_poll``, ``cancelled``,
+        ``timed_out``, ``admitted``, ``rejected``, ``shed``,
+        ``rejected_backpressure``, ``rejected_fairness``,
         ``failed``, ``step_capped``, ``retries``, ``faults_injected``,
         ``checkpoints``, ``quarantined``, ``failovers``,
         ``per_tenant``, ``big_busy_per_worker``, ``big_imbalance``,
@@ -1514,7 +1504,6 @@ class MBEServer:
         self._work_words = dict.fromkeys(WORK_PASSES, 0)
         self._stepwise_steps = 0
         self._n_launches = 0
-        self._rebalanced_steps = 0
         self._n_cancelled = 0
         self._n_timed_out = 0
         self._n_failed = 0

@@ -1,4 +1,1 @@
-from repro.sharding.axes import (  # noqa: F401
-    Rules, use_rules, constrain, spec_for, current_rules,
-    train_rules, serve_rules, named_sharding,
-)
+from repro.sharding.axes import MBE_LANE_AXIS, mbe_serve_mesh  # noqa: F401

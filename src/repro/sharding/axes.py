@@ -1,43 +1,20 @@
-"""Logical-axis sharding rules.
+"""The MBE serving mesh axis.
 
-Every tensor dimension in the model stack carries a *logical* name
-(``act_batch``, ``p_ff``, ``cache_seq``, ...). A ``Rules`` table maps logical
-names to mesh axes for the current execution mode; ``constrain`` applies
-``with_sharding_constraint`` inside jit. This is the one place where the
-parallelism layout (DP / FSDP / TP / EP / sequence-sharded decode) is
-decided — models never name mesh axes directly.
-
-Layouts
--------
-train  : batch over (pod, data); TP over model for heads/ff/vocab/experts;
-         ZeRO-3/FSDP: parameter 'p_embed' dim sharded over data (GSPMD
-         inserts the per-layer all-gathers); pods replicate the FSDP shards
-         (cross-pod traffic is gradient all-reduce only).
-serve  : parameters TP-only over model (no per-step weight gathers);
-         decode KV cache sharded over sequence (flash-decode: softmax
-         reductions over the sharded axis become psums) and batch over
-         (pod, data) when it divides.
+The serving executors (``repro.serving.executor``) place graph lanes on a
+1-D mesh of their own: ``ShardedExecutor`` shards a bucket's lane pool over
+it (one graph per lane, lanes strided across devices) and the big-graph
+work-stealing lane spreads ONE graph's root tasks over the same axis.  The
+axis is named here so the executors never invent mesh axis names of their
+own.
 """
 from __future__ import annotations
 
-import contextlib
-import dataclasses
-import threading
 from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
-# ---------------------------------------------------------------------------
-# MBE serving mesh axis
-# ---------------------------------------------------------------------------
-# The serving executors (repro.serving.executor) place graph lanes on a 1-D
-# mesh of their own: ``ShardedExecutor`` shards a bucket's lane pool over it
-# (one graph per lane, lanes strided across devices) and the big-graph
-# work-stealing lane spreads ONE graph's root tasks over the same axis.
-# Named here — next to the LM layouts — so the axis vocabulary stays in one
-# place; the executors never invent mesh axis names of their own.
 MBE_LANE_AXIS = "mbe_lanes"
 
 
@@ -58,141 +35,3 @@ def mbe_serve_mesh(n_devices: Optional[int] = None,
                 f"XLA_FLAGS=--xla_force_host_platform_device_count=N)")
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis,))
-
-
-@dataclasses.dataclass(frozen=True)
-class Rules:
-    table: dict[str, tuple[str, ...] | None]
-    mesh: Optional[Mesh] = None
-
-    def axes(self, name: str | None):
-        if name is None:
-            return None
-        if name not in self.table:
-            raise KeyError(f"unknown logical axis {name!r}")
-        return self.table[name]
-
-
-_STATE = threading.local()
-
-
-def current_rules() -> Optional[Rules]:
-    return getattr(_STATE, "rules", None)
-
-
-@contextlib.contextmanager
-def use_rules(rules: Optional[Rules]):
-    prev = current_rules()
-    _STATE.rules = rules
-    try:
-        yield rules
-    finally:
-        _STATE.rules = prev
-
-
-def spec_for(logical: tuple[str | None, ...],
-             rules: Optional[Rules] = None) -> P:
-    r = rules or current_rules()
-    if r is None:
-        return P()
-    return P(*[r.axes(n) for n in logical])
-
-
-def constrain(x: jax.Array, *logical: str | None) -> jax.Array:
-    """Apply a sharding constraint by logical dim names (no-op outside
-    rules / outside jit-traceable contexts)."""
-    r = current_rules()
-    if r is None or r.mesh is None:
-        return x
-    assert len(logical) == x.ndim, (logical, x.shape)
-    spec = spec_for(logical, r)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(r.mesh, spec))
-
-
-def named_sharding(logical: tuple[str | None, ...],
-                   rules: Optional[Rules] = None) -> NamedSharding:
-    r = rules or current_rules()
-    assert r is not None and r.mesh is not None
-    return NamedSharding(r.mesh, spec_for(logical, r))
-
-
-# ---------------------------------------------------------------------------
-# rule tables
-# ---------------------------------------------------------------------------
-
-def _batch_axes(multi_pod: bool) -> tuple[str, ...]:
-    return ("pod", "data") if multi_pod else ("data",)
-
-
-def train_rules(mesh: Mesh, multi_pod: bool = False,
-                fsdp: bool = True) -> Rules:
-    b = _batch_axes(multi_pod)
-    return Rules(mesh=mesh, table={
-        # activations
-        "act_batch": b,
-        # Megatron-style sequence parallelism: the between-block residual
-        # stream (and therefore every remat-saved layer input) shards over
-        # the model axis; GSPMD inserts the gather before attention/FFN and
-        # the reduce-scatter after — per-device activation memory drops by
-        # the TP degree, which is what lets train_4k fit HBM.
-        "act_seq": ("model",),
-        "act_embed": None,
-        "act_heads": ("model",),
-        "act_kv": ("model",),
-        "act_ff": ("model",),
-        "act_vocab": ("model",),
-        "act_expert": ("model",),
-        "act_group": b,          # MoE dispatch groups follow the batch
-        "act_inner": ("model",),  # ssm / mlstm inner width
-        # params
-        "p_embed": ("data",) if fsdp else None,
-        "p_vocab": ("model",),
-        "p_heads": ("model",),
-        "p_kv": ("model",),
-        "p_ff": ("model",),
-        "p_expert": ("model",),
-        "p_inner": ("model",),
-        "p_none": None,
-        # caches unused in training
-        "cache_seq": None,
-        "cache_batch": b,
-    })
-
-
-def serve_rules(mesh: Mesh, multi_pod: bool = False,
-                batch_shardable: bool = True) -> Rules:
-    b = _batch_axes(multi_pod)
-    # long-context single-sequence decode: the cache's sequence dim takes
-    # every axis the batch cannot use
-    if batch_shardable:
-        cache_seq = ("model",)
-        batch = b
-    else:
-        cache_seq = (_batch_axes(multi_pod) + ("model",))
-        batch = None
-    return Rules(mesh=mesh, table={
-        "act_batch": batch,
-        # prefill runs the same context-parallel forward as training: the
-        # residual stream shards over (model x seq); decode has no seq dim
-        # so the entry is inert there.
-        "act_seq": ("model",),
-        "act_embed": None,
-        "act_heads": ("model",),
-        "act_kv": ("model",),
-        "act_ff": ("model",),
-        "act_vocab": ("model",),
-        "act_expert": ("model",),
-        "act_group": batch,
-        "act_inner": ("model",),
-        "p_embed": None,          # TP-only: no per-step weight gathers
-        "p_vocab": ("model",),
-        "p_heads": ("model",),
-        "p_kv": ("model",),
-        "p_ff": ("model",),
-        "p_expert": ("model",),
-        "p_inner": ("model",),
-        "p_none": None,
-        "cache_seq": cache_seq,
-        "cache_batch": batch,
-    })

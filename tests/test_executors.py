@@ -43,7 +43,7 @@ def test_plan_route_thresholds():
 def test_routing_log_records_decisions():
     """Every admit leaves a routing entry (which route, why) and every pool
     creation records its lane count and placement — the operator-visible
-    trail ``launch/serve.py --mbe`` prints."""
+    trail ``launch/serve.py`` prints."""
     srv = MBEServer(BucketPolicy(mode="pow2", max_batch=4,
                                  big_graph_threshold=14))
     heavy = dense_small(16, 32, p=0.5, seed=3, name="heavy")
