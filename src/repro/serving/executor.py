@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import types
 
 import numpy as np
 import jax
@@ -91,9 +92,14 @@ class LanePool:
     pytrees (whatever types ``engine`` mints) plus their static shape.
     Owned and mutated exclusively by an ``Executor``; the scheduler holds
     the host-side slot bookkeeping (which request occupies which lane) and
-    never touches the arrays directly."""
+    never touches the arrays directly.
 
-    __slots__ = ("cfg", "B", "engine", "state", "ctx")
+    ``readout`` is a host copy of the state's per-lane scalar leaves
+    (``_scalar_leaves``) taken by the last round's telemetry read.  Every
+    assignment to ``state`` drops it, so it never describes a state that
+    lane surgery has replaced since."""
+
+    __slots__ = ("cfg", "B", "engine", "_state", "ctx", "readout")
 
     def __init__(self, cfg: ed.EngineConfig, n_lanes: int,
                  engine: Engine | None = None):
@@ -102,6 +108,15 @@ class LanePool:
         self.engine = engine or DENSE
         self.state = None
         self.ctx = None
+
+    @property
+    def state(self):
+        return self._state
+
+    @state.setter
+    def state(self, value):
+        self._state = value
+        self.readout = None
 
 
 @dataclasses.dataclass
@@ -118,14 +133,17 @@ class RoundTelemetry:
     #                               engines that count none)
 
 
-def _progress(engine: Engine, state):
-    """What a round's telemetry reads of a pool's state: the lanes'
-    ``steps`` and, for engines that count it, their ``work_rows``, in
-    one device-to-host read."""
-    work = engine.work_rows(state)
-    if work is None:
-        return np.asarray(state.steps), None
-    return jax.device_get((state.steps, work))
+def _scalar_leaves(state) -> dict:
+    """A batched state's per-lane scalar leaves by field name: those of
+    shape ``(B,)`` (task cursor, level, counters, work rows), which the
+    done mask, ``Engine.counters`` and ``Engine.work_rows`` read."""
+    return {k: v for k, v in state._asdict().items() if v.ndim == 1}
+
+
+def _host_scalars(scalars: dict) -> types.SimpleNamespace:
+    """Host NumPy copies of ``_scalar_leaves``, readable by field name as
+    a state is, in one ``device_get``."""
+    return types.SimpleNamespace(**jax.device_get(scalars))
 
 
 def _widen(work: dict | None) -> dict | None:
@@ -283,17 +301,33 @@ class Executor(abc.ABC):
 
     # -- demux views ----------------------------------------------------
     def lane(self, pool: LanePool, i: int) -> ed.DenseState:
-        """Host-readable view of one lane's state (for demux)."""
+        """One lane's whole state, every leaf sliced on the device (a
+        collecting demux, checkpoints, cancel, deadline and step-cap
+        eviction)."""
         return jax.tree.map(lambda x, i=i: x[i], pool.state)
 
+    def _scalars(self, pool: LanePool) -> types.SimpleNamespace:
+        """The pool's per-lane scalar state on the host: the last round's
+        readout, or, where the state has changed since, one fresh read
+        (kept as the readout)."""
+        if pool.readout is None:
+            pool.readout = _host_scalars(_scalar_leaves(pool.state))
+        return pool.readout
+
+    def lane_scalars(self, pool: LanePool, i: int) -> types.SimpleNamespace:
+        """Lane ``i``'s scalar state from ``_scalars``: what
+        ``Engine.counters`` reads, with no device work."""
+        return types.SimpleNamespace(
+            **{k: v[i] for k, v in vars(self._scalars(pool)).items()})
+
     def done_mask(self, pool: LanePool) -> np.ndarray:
-        return np.asarray(pool.engine.done(pool.state))
+        return np.asarray(pool.engine.done(self._scalars(pool)))
 
     def steps(self, pool: LanePool) -> np.ndarray:
         """Per-lane cumulative engine steps (for step-cap enforcement) —
         part of the interface so the scheduler never reads the
         executor-owned pool arrays directly."""
-        return np.asarray(pool.state.steps)
+        return self._scalars(pool).steps
 
     # -- placement / big-graph lane -------------------------------------
     @abc.abstractmethod
@@ -334,13 +368,16 @@ class LocalExecutor(Executor):
                   budget: int | None, unroll: int = 1) -> RoundTelemetry:
         entry = cache.get_round(pool.cfg, pool.B, budget,
                                 engine=pool.engine, unroll=unroll)
-        steps0, work0 = _progress(pool.engine, pool.state)
+        before = self._scalars(pool)
         out, wall, compile_s = entry.timed_call(pool.ctx, pool.state)
-        pool.state = out
-        steps1, work1 = _progress(pool.engine, out)
+        after = _host_scalars(_scalar_leaves(out))
+        pool.state = out            # drops the previous round's readout
+        pool.readout = after
+        eng = pool.engine
         return RoundTelemetry(wall_s=wall, compile_s=compile_s,
-                              adv=steps1 - steps0,
-                              work=_delta(work1, work0))
+                              adv=after.steps - before.steps,
+                              work=_delta(eng.work_rows(after),
+                                          eng.work_rows(before)))
 
     def placement(self, n_lanes: int) -> str:
         return f"1 device x {n_lanes} vmap lanes"
@@ -421,8 +458,9 @@ class ShardedExecutor(Executor):
         entry = cache.get_entry(key, build)
         (out, telem), wall, compile_s = entry.timed_call(pool.ctx,
                                                          pool.state)
-        pool.state = out
-        telem = jax.device_get(telem)
+        telem, scal = jax.device_get((telem, _scalar_leaves(out)))
+        pool.state = out            # drops the previous round's readout
+        pool.readout = types.SimpleNamespace(**scal)
         return RoundTelemetry(
             wall_s=wall, compile_s=compile_s,
             adv=np.asarray(telem["busy_steps"]),

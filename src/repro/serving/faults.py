@@ -257,6 +257,9 @@ class FaultInjector(Executor):
     def lane(self, pool, i):
         return self.inner.lane(pool, i)
 
+    def lane_scalars(self, pool, i):
+        return self.inner.lane_scalars(pool, i)
+
     def done_mask(self, pool: LanePool) -> np.ndarray:
         mask = self.inner.done_mask(pool)
         if self._fire("done_mask", self.plan.corrupt_done_rate) \

@@ -177,7 +177,7 @@ STATS_SCHEMA: dict[str, type | tuple] = dict(
     poll_s=float, refill_s=float, demux_s=float, exec_s=float,
     installs=int, install_fallbacks=int,
     gathered_select_words=int, gathered_check_words=int,
-    stepwise_steps=int)
+    stepwise_steps=int, demux_device_reads=int)
 
 # Monotonic counters (reset by ``MBEServer.reset_stats``); everything
 # else in STATS_SCHEMA is a gauge or a configuration echo.
@@ -191,7 +191,8 @@ MONOTONIC_STATS = frozenset((
     "hits", "misses", "evictions",
     "poll_s", "refill_s", "demux_s", "exec_s",
     "installs", "install_fallbacks",
-    "gathered_select_words", "gathered_check_words", "stepwise_steps"))
+    "gathered_select_words", "gathered_check_words", "stepwise_steps",
+    "demux_device_reads"))
 
 # The kernel passes whose work an engine may count (``Engine.work_rows``),
 # reported in stats() as ``<pass>_words``.
@@ -398,13 +399,21 @@ class _LanePool:
     def demux(self, server: "MBEServer") -> dict[int, EngineResult]:
         """Decode every finished lane into a result and free its slot.
         The payload comes from ``Engine.finish`` — the scheduler never
-        names a concrete result class."""
+        names a concrete result class.  The done mask and the counters
+        come from the round's host readout of the lanes' scalar state;
+        a lane's whole state is sliced on the device only when its
+        collect buffers are decoded."""
         done = server._pool_done_mask(self)
+        whole = server.collect and server.engine.collectable
         results: dict[int, EngineResult] = {}
         for i, r in enumerate(self.reqs):
             if r is None or not done[i]:
                 continue
-            lane = server.executor.lane(self.pool, i)
+            if whole:
+                server._demux_device_reads += 1
+                lane = server.executor.lane(self.pool, i)
+            else:
+                lane = server.executor.lane_scalars(self.pool, i)
             payload = server.engine.finish(
                 self.cfg, lane, n_u=r.graph.n_u, n_v=r.graph.n_v,
                 swapped=r.swapped, collect=server.collect)
@@ -509,6 +518,8 @@ class MBEServer:
         self._n_install_fallbacks = 0   # lanes placed by row surgery
         self._work_words = dict.fromkeys(WORK_PASSES, 0)
         self._stepwise_steps = 0       # lane steps of per-step rounds
+        self._demux_device_reads = 0   # lane slices + done masks read
+        #                                with no round readout
         self._n_launches = 0
         self._n_cancelled = 0
         self._n_timed_out = 0
@@ -999,7 +1010,11 @@ class MBEServer:
         attached, the read is VERIFIED (two consecutive agreeing reads)
         so a transiently corrupted scoreboard read cannot demux an
         unfinished lane or strand a finished one; without one, it is the
-        plain single read (byte-identical off path)."""
+        plain single read (byte-identical off path).  The mask comes from
+        the pool's round readout; where lane surgery has replaced the
+        state since, from one fresh read (``demux_device_reads``)."""
+        if lanepool.pool.readout is None:
+            self._demux_device_reads += 1
         if self.retry is None:
             return self.executor.done_mask(lanepool.pool)
         mask, mismatches = verified_read(
@@ -1458,6 +1473,9 @@ class MBEServer:
                     # lane steps advanced on the dense engine's
                     # lane-masked per-step loop (Engine.stepwise_lanes)
                     stepwise_steps=self._stepwise_steps,
+                    # device reads the demux made: whole-lane slices
+                    # (collect) and done masks with no round readout
+                    demux_device_reads=self._demux_device_reads,
                     **self.cache.stats())
 
     def reset_stats(self) -> None:
@@ -1479,8 +1497,8 @@ class MBEServer:
         ``exec_s``, the placement counters ``installs`` and
         ``install_fallbacks``, the kernel work counters
         ``gathered_select_words`` and ``gathered_check_words``, the
-        per-step loop's ``stepwise_steps``, and the
-        cache counters ``hits``/``misses``/
+        per-step loop's ``stepwise_steps``, the demux's
+        ``demux_device_reads``, and the cache counters ``hits``/``misses``/
         ``evictions`` (so the miss count stays an honest per-phase
         compile count).
 
@@ -1503,6 +1521,7 @@ class MBEServer:
         self._n_install_fallbacks = 0
         self._work_words = dict.fromkeys(WORK_PASSES, 0)
         self._stepwise_steps = 0
+        self._demux_device_reads = 0
         self._n_launches = 0
         self._n_cancelled = 0
         self._n_timed_out = 0

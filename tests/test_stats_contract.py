@@ -312,3 +312,22 @@ def test_work_counters_are_contract_keys():
     srv.reset_stats()
     for key in WORK_COUNTERS:
         assert srv.stats()[key] == 0
+
+
+def test_demux_device_reads_is_a_contract_key():
+    """In the schema as an int, monotonic, one per finished lane that a
+    collecting stream slices whole, 0 without collect, and zeroed by
+    ``reset_stats``."""
+    assert STATS_SCHEMA["demux_device_reads"] is int
+    assert "demux_device_reads" in MONOTONIC_STATS
+    for collect in (False, True):
+        srv = MBEServer(BucketPolicy(max_batch=2, steps_per_round=8),
+                        collect=collect, collect_cap=8)
+        for g in _graphs_for("dense", n=3):
+            srv.admit(g)
+        got = srv.drain()
+        stats = srv.stats()
+        _assert_schema(stats)
+        assert stats["demux_device_reads"] == (len(got) if collect else 0)
+        srv.reset_stats()
+        assert srv.stats()["demux_device_reads"] == 0
