@@ -1,7 +1,9 @@
-"""Host time in lane-pool demuxes (``mbe.demux``: the done-mask read, the
-finished lanes' slices and their counters read back) over the window, in
-%: the delta of the program's ``stats()`` counter ``demux_s``.  ``None``
-where the program has no such counter."""
+"""Host time in lane-pool demuxes (``mbe.demux``: the finished lanes'
+done mask and counters taken from the round's host readout of the lanes'
+scalar state, decoded into results; a device read only where lane surgery
+replaced the state since the round, or a lane's collect buffers are
+decoded) over the window, in %: the delta of the program's ``stats()``
+counter ``demux_s``.  ``None`` where the program has no such counter."""
 
 
 def read(run):

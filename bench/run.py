@@ -10,16 +10,18 @@ service's ``MBEOptions``, its guarantee, its scale) and a traffic mix
 1. refuses any platform but a TPU, and fewer chips than the cell asks;
 2. builds one ``MBEClient`` and the cell's requests from ``--seed``;
 3. warms every executable the window can use: for each shape bucket the
-   requests reach, one pool of each lane count the scheduler can plan;
+   requests reach, one pool of each lane count the scheduler can plan,
+   or, for a bucket on the big-graph route, one graph through that lane;
 4. offers the mix's load through ``MBEClient.submit`` / ``client.poll``
    for ``--seconds``, a closed loop of a fixed number of outstanding
-   requests
+   requests, with at most a fixed number of some kinds among them
    (with ``--trace 1`` under the profiler, each call in a host span);
 5. reads the metrics the manifest lists for the cell: end-to-end ones
    (``bench/e2e/<name>.py``) with ``--trace 0``, per-layer ones
    (``bench/layers/<name>.py``) with ``--trace 1``;
-6. frees the program, checks a seeded sample of the answers against the
-   plain reference (``bench/check.py``), and prints the result as one
+6. frees the program, checks every answer of the kinds the mix lists and
+   a seeded sample of the others against the plain reference
+   (``bench/check.py``), and prints the result as one
    JSON line, last on stdout, with each compared number and its limit
    last on stderr too.
 
@@ -30,6 +32,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import contextlib  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
@@ -125,20 +128,25 @@ def lane_counts(options) -> list[int]:
 
 def warm(client, requests: list, spans) -> int:
     """Serve near-empty graphs of a member's shape through every bucket
-    the requests reach, so that the window compiles nothing; returns how
-    many warm requests ran.
+    the requests reach, on the route each takes (``plan_route``), so that
+    the window compiles nothing; returns how many warm requests ran.
 
-    Lane counts and refills: for each pool width ``B`` the scheduler can
-    plan, one slow graph (a crown, ``traffic.slow_graph``) holds a lane of
-    a ``B``-lane pool while ``k = 1 .. B-1`` fast graphs are refilled
-    beside it, then ``B`` more make the pool grow; this reaches the
-    executables of every ``(bucket, B)`` and the lane-surgery shapes of
-    every refill count."""
-    from repro.serving.buckets import plan_bucket
+    Lane buckets, lane counts and refills: for each pool width ``B`` the
+    scheduler can plan, one slow graph (a crown, ``traffic.slow_graph``)
+    holds a lane of a ``B``-lane pool while ``k = 1 .. B-1`` fast graphs
+    are refilled beside it, then ``B`` more make the pool grow; this
+    reaches the executables of every ``(bucket, B)`` and the lane-surgery
+    shapes of every refill count.
+
+    Big-route buckets: the big-graph lane serves one graph at a time with
+    one round executable per bucket, which every round of every graph of
+    the bucket reuses, so one warm graph served to its end reaches it."""
+    from repro.serving.buckets import plan_bucket, plan_route
     policy = client.options.bucket_policy()
-    shapes = {}
+    shapes, big = {}, {}
     for g in requests:
-        shapes.setdefault(plan_bucket(g, policy), (g.n_u, g.n_v))
+        (big if plan_route(g, policy) == "big" else shapes).setdefault(
+            plan_bucket(g, policy), (g.n_u, g.n_v))
     widths = lane_counts(client.options)
     n = [0]
 
@@ -168,6 +176,9 @@ def warm(client, requests: list, spans) -> int:
             while not slow.done():
                 with spans("bench.warm"):
                     client.poll()
+    for b in sorted(big, key=lambda b: (b.n_u, b.n_v)):
+        nu, nv = big[b]
+        serve([traffic.warm_graph(nu, nv, f"warm-big-{nu}x{nv}")])
     return n[0]
 
 
@@ -179,12 +190,41 @@ def serve_window(client, requests: list, loop: dict, seconds: float,
     ``seconds``, each timed from its submission, then wait up to
     ``grace_s`` for the requests still out.  The window's metrics read
     the requests answered before it closed; requests not answered by the
-    end of the grace are ``missing``."""
+    end of the grace are ``missing``.
+
+    ``loop['max_in_flight']``, ``{kind label: k}``, keeps at most ``k``
+    requests of that kind out.  A request whose kind is full is held, and
+    the loop takes the next request of another kind; a held request is
+    first in line once its kind has room, so the order follows from the
+    seed and the completions alone."""
     out: dict[int, tuple] = {}      # rid -> (future, t_submit, request)
     done: list[dict] = []
-    nxt = 0
+    caps = loop.get("max_in_flight", {})
+    live = collections.Counter()    # requests out, per kind
+    held: collections.deque = collections.deque()   # request indices
+    kinds = {traffic.label(g) for g, _ in requests}
+    nxt = attempted = 0
     t0 = time.perf_counter()
     stop = t0 + seconds
+
+    def has_room(kind):
+        return kind not in caps or live[kind] < caps[kind]
+
+    def take():
+        """The next request to submit, or None where every kind is full."""
+        nonlocal nxt
+        for k, i in enumerate(held):
+            if has_room(traffic.label(requests[i][0])):
+                del held[k]
+                return i
+        if not any(has_room(kind) for kind in kinds):
+            return None
+        while True:
+            i = nxt % len(requests)
+            nxt += 1
+            if has_room(traffic.label(requests[i][0])):
+                return i
+            held.append(i)
 
     def collect(batch, t):
         for rid in batch:
@@ -192,6 +232,7 @@ def serve_window(client, requests: list, loop: dict, seconds: float,
             if item is None:
                 continue
             fut, ts, g = item
+            live[traffic.label(g)] -= 1
             with spans("bench.result"):
                 res = fut.result()
             done.append(dict(latency_s=t - ts, status=res.status,
@@ -200,8 +241,12 @@ def serve_window(client, requests: list, loop: dict, seconds: float,
     with spans(trace_mod.WINDOW):
         while time.perf_counter() < stop:
             while len(out) < loop["outstanding"]:
-                g, p = requests[nxt % len(requests)]
-                nxt += 1
+                i = take()
+                if i is None:
+                    break
+                g, p = requests[i]
+                attempted += 1
+                live[traffic.label(g)] += 1
                 with spans("bench.submit"):
                     fut = client.submit(p)
                 out[fut.rid] = (fut, time.perf_counter(), g)
@@ -213,10 +258,25 @@ def serve_window(client, requests: list, loop: dict, seconds: float,
     in_window = len(done)
     while out and time.perf_counter() < t1 + grace_s:
         collect(client.poll(), time.perf_counter())
-    return dict(window_s=t1 - t0, attempted=nxt,
+    return dict(window_s=t1 - t0, attempted=attempted,
                 wrapped=max(nxt - len(requests), 0), answers=done,
                 measured=done[:in_window], missing=len(out),
                 stats_after=stats)
+
+
+def check_sample(done: list[dict], check_cfg: dict, seed: int) -> list[int]:
+    """Indices of the ``done`` answers compared with the reference: every
+    answer of a kind listed under ``check_cfg['every']``, and a sample of
+    ``check_cfg['sample']`` of the rest drawn from the seed."""
+    every = set(check_cfg.get("every", ()))
+    listed = [i for i, a in enumerate(done)
+              if traffic.label(a["graph"]) in every]
+    rest = [i for i, a in enumerate(done)
+            if traffic.label(a["graph"]) not in every]
+    n_sample = min(check_cfg["sample"], len(rest))
+    pick = np.random.default_rng(seed).choice(
+        len(rest), n_sample, replace=False) if n_sample else []
+    return sorted(listed + [rest[i] for i in pick])
 
 
 # -- one run ----------------------------------------------------------------
@@ -307,11 +367,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     answers = win["answers"]
     not_done = sum(a["status"] != "done" for a in answers)
     done = [a for a in answers if a["status"] == "done"]
-    n_sample = min(mix["check"]["sample"], len(done))
-    pick = sorted(np.random.default_rng(seed).choice(
-        len(done), n_sample, replace=False)) if n_sample else []
     sample = [(done[i]["graph"].rows, done[i]["n_max"], done[i]["cs"])
-              for i in pick]
+              for i in check_sample(done, mix["check"], seed)]
     attempted, missing = win["attempted"], win["missing"]
     failed = not_done + missing
     # the reference runs after the window, with the program's state freed

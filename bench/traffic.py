@@ -10,7 +10,15 @@ reads the same rows.
 A mix lists its graph kinds under ``graphs``: each a published shape
 (``n_u``, ``n_v``, ``edges``: exactly that many distinct edges, every
 vertex of degree 1 or more, as in the published dataset), the family its
-edges are drawn from, and its ``share`` of the requests.
+edges are drawn from, and its ``share`` of the requests.  Any other key of
+a kind is a parameter of its family.
+
+Families: ``uniform`` (no degree skew) and ``powerlaw`` (rank-Zipf
+degrees on both sides, with the kind's exponents ``a_u`` and ``a_v``).
+A mix that uses ``powerlaw`` states its exponents, and where they come
+from, under its ``assumed``: a published dataset gives its shape and edge
+count, not the exponents of its degree distribution, so they are an
+assumption and are named as one.
 
 Steadiness: the requests come in rounds, each round holding every kind
 ``share`` times in a seed-shuffled order, so any prefix of the sequence
@@ -69,7 +77,37 @@ def uniform(n_u, n_v, edges, rng):
     return rows
 
 
-FAMILIES = dict(uniform=uniform)
+def powerlaw(n_u, n_v, edges, rng, a_u, a_v):
+    """The cover, then distinct edges up to ``edges``, each new edge drawn
+    with probability proportional to ``w_u[i] * w_v[j]``, where
+    ``w[k] = (k + 1) ** -a`` is the rank-Zipf weight of vertex ``k``.
+    Independent draws from the product kept in order of first appearance
+    are a draw without replacement in that proportion."""
+    def p(n, a):
+        w = np.arange(1, n + 1, dtype=np.float64) ** -float(a)
+        return w / w.sum()
+    p_u, p_v = p(n_u, a_u), p(n_v, a_v)
+    rows = _cover(n_u, n_v, rng)
+    flat = rows.reshape(-1)
+    have = int(flat.sum())
+    while have < edges:
+        m = 2 * (edges - have)
+        code = rng.choice(n_u, size=m, p=p_u) * n_v \
+            + rng.choice(n_v, size=m, p=p_v)
+        code, first = np.unique(code, return_index=True)
+        new = code[np.argsort(first)]
+        new = new[~flat[new]][: edges - have]
+        flat[new] = True
+        have += len(new)
+    return rows
+
+
+def label(g: Graph) -> str:
+    """The mix kind a request of ``generate`` was drawn from."""
+    return g.name.split("-", 1)[1]
+
+
+FAMILIES = dict(uniform=uniform, powerlaw=powerlaw)
 _SHAPE = ("label", "family", "n_u", "n_v", "edges", "share", "source")
 
 
